@@ -8,8 +8,9 @@ provides, each trial in a **fresh subprocess** so the in-process jit cache
 really is cold:
 
 - ``cold``     plain service: the first request compiles the chunk program;
-- ``persist``  persistent XLA compilation cache (pre-primed directory):
-               the compile is replaced by an executable cache load;
+- ``persist``  persistent XLA compilation cache, in the directory
+               ``programs.compile_cache_dir`` picks (pre-primed): the
+               compile is replaced by an executable cache load;
 - ``warmed``   ``warm_programs`` AOT-compiles the bucket before the
                request: the request dispatches a cached executable.
 
@@ -45,18 +46,23 @@ SMOKE_CASE = dict(n=24, batch=4, chunk=3, iterations=6, variant="mmas",
                   seed=0, repeats=1, max_ratio=0.8)
 
 
-def _child(case: dict, mode: str, cache_dir: str) -> dict:
+def _child(case: dict, mode: str) -> dict:
     """One trial, run inside this (fresh) process: build the service,
     apply the mode's mitigation, then time the first request end to end
     (submit -> result).  Prints one JSON line on stdout."""
     t_import0 = time.perf_counter()
+    import jax
     from repro.core import aco, tsp
     from repro.solver import (ProgramCache, StreamingSolverService,
-                              enable_persistent_cache)
+                              compile_cache_dir, enable_persistent_cache)
     import_s = time.perf_counter() - t_import0
 
     if mode == "persist":
-        enable_persistent_cache(cache_dir)
+        enable_persistent_cache(compile_cache_dir())
+    else:
+        # JAX turns the cache on by itself when JAX_COMPILATION_CACHE_DIR
+        # is set; the other modes must compile for real.
+        jax.config.update("jax_enable_compilation_cache", False)
     cfg = aco.ACOConfig(variant=case["variant"],
                         iterations=case["iterations"], seed=case["seed"])
     programs = ProgramCache() if mode == "warmed" else None
@@ -80,10 +86,9 @@ def _child(case: dict, mode: str, cache_dir: str) -> dict:
             "hits": programs.stats()["hits"] if programs else 0}
 
 
-def _spawn(case: dict, mode: str, cache_dir: str) -> dict:
+def _spawn(case: dict, mode: str) -> dict:
     """Run one trial in a fresh interpreter (cold in-process jit cache)."""
-    payload = json.dumps({"case": case, "mode": mode,
-                          "cache_dir": cache_dir})
+    payload = json.dumps({"case": case, "mode": mode})
     env = dict(os.environ)
     env["PYTHONPATH"] = os.pathsep.join(
         [os.path.join(_ROOT, "src"), env.get("PYTHONPATH", "")])
@@ -105,14 +110,13 @@ def _percentiles(samples: list[float]) -> dict:
 
 
 def main(case: dict, out_path: str = DEFAULT_OUT) -> dict:
-    cache_dir = tempfile.mkdtemp(prefix="coldstart_xla_")
     # Prime the persistent cache once (this run's compile populates the
     # directory; it is *not* timed as a persist sample).
-    _spawn(case, "persist", cache_dir)
+    _spawn(case, "persist")
 
     rows = {}
     for mode in ("cold", "persist", "warmed"):
-        trials = [_spawn(case, mode, cache_dir)
+        trials = [_spawn(case, mode)
                   for _ in range(case["repeats"])]
         rows[mode] = _percentiles([t["first_request_s"] for t in trials])
         rows[mode]["warm_s_mean"] = float(
@@ -157,8 +161,7 @@ if __name__ == "__main__":
     args = ap.parse_args()
     if args.child:
         spec = json.loads(args.child)
-        print(json.dumps(_child(spec["case"], spec["mode"],
-                                spec["cache_dir"])))
+        print(json.dumps(_child(spec["case"], spec["mode"])))
         sys.exit(0)
     case = SMOKE_CASE if (args.smoke or args.dry) else CASE
     out = args.out or (os.path.join(tempfile.mkdtemp(prefix="coldstart_"),

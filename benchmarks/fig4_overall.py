@@ -61,4 +61,7 @@ def main(sizes=SIZES):
 
 
 if __name__ == "__main__":
-    main()
+    import argparse
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--full", action="store_true")
+    main(FULL_SIZES if ap.parse_args().full else SIZES)

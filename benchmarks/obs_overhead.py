@@ -21,7 +21,7 @@ arrival trace through the StreamingSolverService at:
 Each level replays best-of-``REPS`` (min wall) to damp scheduler noise;
 the summary reports full/off and serving/off throughput and whether
 each holds the <=5% overhead bar.  Emits ``BENCH_obs.json`` at the repo
-root.
+root (``--smoke`` writes no JSON unless ``--out`` is given).
 
     PYTHONPATH=src python benchmarks/obs_overhead.py [--smoke]
 """
@@ -205,8 +205,12 @@ def main(case=CASE, out_path: str | None = DEFAULT_OUT):
 
 if __name__ == "__main__":
     ap = argparse.ArgumentParser()
-    ap.add_argument("--smoke", action="store_true", help="small fast case")
+    ap.add_argument("--smoke", action="store_true",
+                    help="small fast case, no JSON unless --out")
     ap.add_argument("--out", default=None,
                     help=f"output JSON path (default: {DEFAULT_OUT})")
     args = ap.parse_args()
-    main(SMOKE_CASE if args.smoke else CASE, args.out or DEFAULT_OUT)
+    if args.smoke:
+        main(SMOKE_CASE, args.out)
+    else:
+        main(CASE, args.out or DEFAULT_OUT)

@@ -33,7 +33,7 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from . import aco, pheromone, quant, strategies, tsp
@@ -134,7 +134,7 @@ def run_islands(instance: tsp.TSPInstance, cfg: IslandConfig, mesh: Mesh,
         best_len=spec, iteration=spec, key=P(island_axes, None))
 
     @partial(shard_map, mesh=mesh, in_specs=(st_specs,),
-             out_specs=st_specs, check_rep=False)
+             out_specs=st_specs, check_vma=False)
     def round_fn(st: aco.ColonyState) -> aco.ColonyState:
         # local leading axis is 1 island per device: vmap over it.
         def one(st1):
@@ -296,9 +296,10 @@ def sharded_colony_step_fn(mesh: Mesh, n: int, cfg: aco.ACOConfig,
         w2 = jnp.concatenate([wrep, wrep])
         t2 = jnp.where((t2 >= 0) & (t2 < nl), t2, -1)
         if use_pallas:
+            from repro.kernels import ops as kops
             from repro.kernels import pheromone_update as pu_k
             tau = pu_k.pheromone_update(st.tau, f2, t2, w2, cfg.rho,
-                                        interpret=True)
+                                        interpret=kops.INTERPRET)
             dep = tau - (1 - cfg.rho) * st.tau
         else:
             valid = t2 >= 0
@@ -312,7 +313,7 @@ def sharded_colony_step_fn(mesh: Mesh, n: int, cfg: aco.ACOConfig,
                                   st.iteration + 1, key), it_len
 
     smapped = shard_map(step, mesh=mesh, in_specs=(dspec, dspec, st_spec),
-                        out_specs=(st_spec, P()), check_rep=False)
+                        out_specs=(st_spec, P()), check_vma=False)
     return jax.jit(smapped)
 
 

@@ -35,6 +35,10 @@ import jax.numpy as jnp
 
 Array = jax.Array
 
+# The one-hot deposits carry the f32 deposit weights in a matmul operand;
+# at default precision the TPU rounds matmul operands to bf16.
+_EXACT = jax.lax.Precision.HIGHEST
+
 
 def evaporate(tau: Array, rho: float) -> Array:
     """Eq. 2: tau <- (1 - rho) tau."""
@@ -127,7 +131,7 @@ def deposit_s2g(n: int, tours: Array, w: Array, row_tile: int = 0,
         def col_block(j0):
             cols = j0 + jnp.arange(bj)
             mj = (tr[None, :] == cols[:, None]).astype(jnp.float32)  # (bj, E)
-            return mi @ mj.T                                          # (bi, bj)
+            return jnp.matmul(mi, mj.T, precision=_EXACT)             # (bi, bj)
 
         blocks = jax.lax.map(col_block, jnp.arange(0, nj, bj))       # (k, bi, bj)
         return blocks.transpose(1, 0, 2).reshape(bi, nj)
@@ -163,7 +167,7 @@ def deposit_onehot(n: int, tours: Array, w: Array, chunk: int = 8,
         ws = jax.lax.dynamic_slice_in_dim(we, i * c, c).ravel()
         F = jax.nn.one_hot(fs, n, dtype=jnp.float32)
         T = jax.nn.one_hot(ts, n, dtype=jnp.float32) * ws[:, None]
-        return acc + F.T @ T, None
+        return acc + jnp.matmul(F.T, T, precision=_EXACT), None
 
     d0 = jnp.zeros((n, n), jnp.float32)
     d, _ = jax.lax.scan(body, d0, jnp.arange(nchunks))

@@ -331,13 +331,15 @@ def tour_length(dist: Array, tour: Array, n_actual: Optional[Array] = None) -> A
     position 0 and phantom-tail edges contribute 0 (masked with ``where``,
     never multiplied — phantom distances are inf).
     """
+    # one element gather per edge: gathering whole rows first
+    # (``dist[tour]``) is an (..., n, n) tensor that XLA does not always
+    # fuse away — 32 GiB for 8 batched colonies of 1024 ants at n = 1024.
     nxt = jnp.roll(tour, -1, axis=-1)
     if n_actual is None:
-        return edge_sum(jnp.take_along_axis(
-            dist[tour], nxt[..., None], axis=-1)[..., 0])
+        return edge_sum(dist[tour, nxt])
     idx = jnp.arange(tour.shape[-1], dtype=jnp.int32)
     nxt = jnp.where(idx == n_actual - 1, tour[..., :1], nxt)
-    d = jnp.take_along_axis(dist[tour], nxt[..., None], axis=-1)[..., 0]
+    d = dist[tour, nxt]
     return edge_sum(jnp.where(idx < n_actual, d, 0.0))
 
 

@@ -1,3 +1,13 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
+"""Pallas kernels for the paper's hot spots, their jnp oracles (ref.py)
+and the jitted public wrappers (ops.py)."""
+from __future__ import annotations
+
+import jax
+
+
+def resolve_interpret(interpret: bool | None) -> bool:
+    """A kernel's ``interpret`` flag: an explicit bool wins; ``None``
+    follows the backend — compiled on a TPU, interpreted elsewhere."""
+    if interpret is None:
+        return jax.default_backend() != "tpu"
+    return interpret

@@ -12,6 +12,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from . import resolve_interpret
+
 DEFAULT_BLOCK_M = 256
 DEFAULT_BLOCK_N = 512
 
@@ -46,7 +48,8 @@ def _choice_kernel(tau_ref, eta_ref, nact_ref, out_ref, *, alpha: float,
 def choice_info(tau: jax.Array, eta: jax.Array, alpha: float = 1.0,
                 beta: float = 2.0, n_actual: jax.Array | None = None,
                 block_m: int = DEFAULT_BLOCK_M,
-                block_n: int = DEFAULT_BLOCK_N, interpret: bool = True) -> jax.Array:
+                block_n: int = DEFAULT_BLOCK_N,
+                interpret: bool | None = None) -> jax.Array:
     """``n_actual``: optional traced () scalar; choice values touching a
     phantom row/column (>= n_actual) are exactly 0 — same as the pure-JAX
     route, where phantom eta == 0 zeroes the product (DESIGN.md §10)."""
@@ -72,6 +75,6 @@ def choice_info(tau: jax.Array, eta: jax.Array, alpha: float = 1.0,
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct(tau.shape, tau.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(tau, eta, n_act)
     return out[:n0, :n1]

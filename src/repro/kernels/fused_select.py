@@ -36,11 +36,34 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from . import resolve_interpret
 from .choice_info import _ipow
-from .tour_select import _transform
+from .tour_select import _transform, first_arg
 
 DEFAULT_BLOCK_M = 8
 DEFAULT_BLOCK_N = 512
+# The full-height (n, bn) tau/eta column tiles are double-buffered in VMEM;
+# keep them inside the TPU's 16 MiB default scoped VMEM by narrowing the
+# tile as n grows (any bn gives the same selection).
+_TILE_VMEM_BUDGET = 12 * 2**20
+
+
+def _fit_block_n(n: int, block_n: int, tau_bytes: int) -> int:
+    # tau (tau_bytes) + eta (f32) per column, two buffers each
+    while block_n > 128 and \
+            2 * n * block_n * (tau_bytes + 4) > _TILE_VMEM_BUDGET:
+        block_n //= 2
+    return block_n
+
+
+def _gather_rows(onehot, tile):
+    """``onehot @ tile`` at full f32 precision: a default-precision f32
+    matmul on the TPU rounds its operands to bf16, which would make the
+    one-hot row gather inexact."""
+    return jax.lax.dot_general(
+        onehot, tile, (((1,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)
 
 
 def _fused_kernel(*refs, mode: str, alpha: float, beta: float,
@@ -56,42 +79,37 @@ def _fused_kernel(*refs, mode: str, alpha: float, beta: float,
         (tau_ref, eta_ref, cur_ref, vis_ref, rand_ref, nact_ref,
          val_ref, idx_ref) = refs
     j = pl.program_id(1)
-    cur = cur_ref[...]                                        # (bm,)
+    cur = cur_ref[...]                                        # (bm, 1)
     rows_iota = jax.lax.broadcasted_iota(jnp.int32, (1, n_rows), 1)
-    onehot = (cur[:, None] == rows_iota).astype(jnp.float32)  # (bm, n)
+    onehot = (cur == rows_iota).astype(jnp.float32)           # (bm, n)
     # Exact gather of the (bm, bn) tau/eta row tiles as an MXU matmul.
     tau_tile = tau_ref[...]
     if quant != "none":
         # int8 in [-127, 127] and bf16 are exactly representable in f32,
         # so the one-hot contraction below stays bitwise a gather.
         tau_tile = tau_tile.astype(jnp.float32)
-    tau_rows = jax.lax.dot_general(
-        onehot, tau_tile, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+    tau_rows = _gather_rows(onehot, tau_tile)
     if quant == "int8":
         # Gather the per-row scale with the same one-hot contraction and
         # multiply after the payload gather: scale is constant along the
         # row, so (gathered q) * (gathered scale) multiplies exactly the
         # operands full dequantise-then-gather would — bitwise equal to
         # the ref.py oracle on the dequantised matrix.
-        srow = jax.lax.dot_general(
-            onehot, scale_ref[...], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)               # (bm, 1)
+        srow = _gather_rows(onehot, scale_ref[...])           # (bm, 1)
         tau_rows = tau_rows * srow
-    eta_rows = jax.lax.dot_general(
-        onehot, eta_ref[...], (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+    eta_rows = _gather_rows(onehot, eta_ref[...])
     w = _ipow(tau_rows, alpha) * _ipow(eta_rows, beta)        # (bm, bn)
 
     cols = j * block_n + jax.lax.broadcasted_iota(
         jnp.int32, w.shape, 1)                                # (bm, bn)
     n_act = nact_ref[0, 0]
-    mask = ((vis_ref[...] == 0) & (cols < n_act)).astype(w.dtype)
+    # widen before comparing: v5e has no int8 vector compare
+    vis = vis_ref[...].astype(jnp.int32)
+    mask = ((vis == 0) & (cols < n_act)).astype(w.dtype)
     v = _transform(w, mask, rand_ref[...], mode)
 
-    tile_val = jnp.max(v, axis=1)
-    local = jnp.argmax(v, axis=1).astype(jnp.int32)           # first max
-    tile_idx = local + j * block_n
+    tile_val, local = first_arg(v)                            # (bm, 1)
+    tile_idx = local + j * block_n                            # first max
 
     @pl.when(j == 0)
     def _init():
@@ -120,7 +138,7 @@ def fused_select(tau: jax.Array, eta: jax.Array, cur: jax.Array,
                  tau_scale: jax.Array | None = None,
                  block_m: int = DEFAULT_BLOCK_M,
                  block_n: int = DEFAULT_BLOCK_N,
-                 interpret: bool = True) -> jax.Array:
+                 interpret: bool | None = None) -> jax.Array:
     """tau/eta (n, n); cur (m,) i32; visited/rand (m, n).  -> (m,) i32.
 
     ``n_actual``: optional traced () scalar; cities >= n_actual (phantom
@@ -143,12 +161,13 @@ def fused_select(tau: jax.Array, eta: jax.Array, cur: jax.Array,
         tau = tau.astype(jnp.float32)
     m, n = visited.shape
     bm = min(block_m, max(m, 1))
-    bn = min(block_n, n)
+    bn = min(_fit_block_n(n, block_n, tau.dtype.itemsize), n)
     pad_m = (-m) % bm
     pad_n = (-n) % bn
     visited = visited.astype(jnp.int8)
+    cur = cur.astype(jnp.int32).reshape(m, 1)
     if pad_m:
-        cur = jnp.pad(cur, (0, pad_m))
+        cur = jnp.pad(cur, ((0, pad_m), (0, 0)))
         visited = jnp.pad(visited, ((0, pad_m), (0, 0)), constant_values=1)
         rand = jnp.pad(rand, ((0, pad_m), (0, 0)), constant_values=1.0)
     if pad_n:
@@ -163,12 +182,12 @@ def fused_select(tau: jax.Array, eta: jax.Array, cur: jax.Array,
     in_specs = [
         pl.BlockSpec((n, bn), lambda i, j: (0, j)),    # tau column tile
         pl.BlockSpec((n, bn), lambda i, j: (0, j)),    # eta column tile
-        pl.BlockSpec((bm,), lambda i, j: (i,)),        # cur
+        pl.BlockSpec((bm, 1), lambda i, j: (i, 0)),    # cur
         pl.BlockSpec((bm, bn), lambda i, j: (i, j)),   # visited
         pl.BlockSpec((bm, bn), lambda i, j: (i, j)),   # rand
         pl.BlockSpec((1, 1), lambda i, j: (0, 0)),     # n_actual
     ]
-    operands = [tau, eta.astype(jnp.float32), cur.astype(jnp.int32),
+    operands = [tau, eta.astype(jnp.float32), cur,
                 visited, rand.astype(jnp.float32), n_act]
     if q_mode == "int8":
         in_specs.insert(1, pl.BlockSpec((n, 1), lambda i, j: (0, 0)))
@@ -180,14 +199,14 @@ def fused_select(tau: jax.Array, eta: jax.Array, cur: jax.Array,
         grid=(gm, gn),
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((bm,), lambda i, j: (i,)),
-            pl.BlockSpec((bm,), lambda i, j: (i,)),
+            pl.BlockSpec((bm, 1), lambda i, j: (i, 0)),
+            pl.BlockSpec((bm, 1), lambda i, j: (i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((mp,), jnp.float32),
-            jax.ShapeDtypeStruct((mp,), jnp.int32),
+            jax.ShapeDtypeStruct((mp, 1), jnp.float32),
+            jax.ShapeDtypeStruct((mp, 1), jnp.int32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(*operands)
     del val
-    return idx[:m]
+    return idx[:m, 0]

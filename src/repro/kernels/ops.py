@@ -20,6 +20,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from . import resolve_interpret
 from . import choice_info as _ci
 from . import fused_select as _fs
 from . import pheromone_update as _pu
@@ -28,11 +29,7 @@ from . import tour_select as _ts
 from . import two_opt as _to
 
 
-def _interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
-
-
-INTERPRET = _interpret_default()
+INTERPRET = resolve_interpret(None)
 
 
 class UnsupportedKernelRoute(NotImplementedError):

@@ -32,9 +32,11 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from . import resolve_interpret
+
 DEFAULT_BLOCK_I = 128
 DEFAULT_BLOCK_J = 128
-DEFAULT_BLOCK_E = 512
+DEFAULT_BLOCK_E = 1024   # XLA tiles 1-D s32/f32 arrays T(1024) on the TPU
 
 
 def _update_kernel(tau_ref, frm_ref, to_ref, w_ref, out_ref, *,
@@ -47,15 +49,18 @@ def _update_kernel(tau_ref, frm_ref, to_ref, w_ref, out_ref, *,
     def _init():
         out_ref[...] = (1.0 - rho) * tau_ref[...]
 
-    frm = frm_ref[...]                       # (be,)
+    frm = frm_ref[...]                       # (1, be)
     to = to_ref[...]
     w = w_ref[...]
-    rows = i * bi + jax.lax.broadcasted_iota(jnp.int32, (1, bi), 1)
-    cols = j * bj + jax.lax.broadcasted_iota(jnp.int32, (1, bj), 1)
-    F = (frm[:, None] == rows).astype(jnp.float32)             # (be, bi)
-    T = (to[:, None] == cols).astype(jnp.float32) * w[:, None]  # (be, bj)
+    rows = i * bi + jax.lax.broadcasted_iota(jnp.int32, (bi, 1), 0)
+    cols = j * bj + jax.lax.broadcasted_iota(jnp.int32, (bj, 1), 0)
+    Ft = (rows == frm).astype(jnp.float32)                     # (bi, be)
+    Tt = (cols == to).astype(jnp.float32) * w                  # (bj, be)
+    # full f32 precision: the weights ride in Tt, and the TPU's default
+    # precision would round them to bf16
     out_ref[...] += jax.lax.dot_general(
-        F, T, (((0,), (0,)), ((), ())),
+        Ft, Tt, (((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32)                     # (bi, bj)
 
 
@@ -68,7 +73,7 @@ def pheromone_update(tau: jax.Array, frm: jax.Array, to: jax.Array,
                      block_i: int = DEFAULT_BLOCK_I,
                      block_j: int = DEFAULT_BLOCK_J,
                      block_e: int = DEFAULT_BLOCK_E,
-                     interpret: bool = True) -> jax.Array:
+                     interpret: bool | None = None) -> jax.Array:
     """tau (n0, n1) f32; frm/to (E,) int32 directed edges; w (E,) f32 deposit.
 
     Returns (1-rho)*tau + D. Pass each undirected edge twice (both
@@ -91,18 +96,19 @@ def pheromone_update(tau: jax.Array, frm: jax.Array, to: jax.Array,
     gi = tau_p.shape[0] // bi
     gj = tau_p.shape[1] // bj
     ge = frm.shape[0] // be
+    # Edges travel as (1, E) rows: a 1-D edge block gains a squeezed
+    # leading dim under vmap, which the TPU's block rules refuse.
+    edge = pl.BlockSpec((1, be), lambda i, j, e: (0, e))
     out = pl.pallas_call(
         functools.partial(_update_kernel, rho=rho, bi=bi, bj=bj),
         grid=(gi, gj, ge),
         in_specs=[
             pl.BlockSpec((bi, bj), lambda i, j, e: (i, j)),
-            pl.BlockSpec((be,), lambda i, j, e: (e,)),
-            pl.BlockSpec((be,), lambda i, j, e: (e,)),
-            pl.BlockSpec((be,), lambda i, j, e: (e,)),
+            edge, edge, edge,
         ],
         out_specs=pl.BlockSpec((bi, bj), lambda i, j, e: (i, j)),
         out_shape=jax.ShapeDtypeStruct(tau_p.shape, jnp.float32),
-        interpret=interpret,
-    )(tau_p, frm.astype(jnp.int32), to.astype(jnp.int32),
-      w.astype(jnp.float32))
+        interpret=resolve_interpret(interpret),
+    )(tau_p, frm.astype(jnp.int32)[None], to.astype(jnp.int32)[None],
+      w.astype(jnp.float32)[None])
     return out[:n0, :n1]

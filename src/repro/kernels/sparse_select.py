@@ -30,8 +30,9 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from . import resolve_interpret
 from .choice_info import _ipow
-from .tour_select import _transform
+from .tour_select import _transform, first_arg
 
 DEFAULT_BLOCK_M = 8
 DEFAULT_BLOCK_N = 512
@@ -55,13 +56,16 @@ def _sparse_kernel(*refs, mode: str, alpha: float, beta: float,
         jnp.int32, cand.shape + (block_n,), 2)                # (bm, K, bn)
     memb = (cand[:, :, None] == cols).astype(jnp.float32)
     # batched one-hot contraction: exact gather of the tile's contribution
+    # (full f32 precision — the TPU's default rounds f32 operands to bf16)
     gv = jax.lax.dot_general(
         memb, vis_ref[...].astype(jnp.float32),
         (((2,), (1,)), ((0,), (0,))),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32)                   # (bm, K)
     gr = jax.lax.dot_general(
         memb, rand_ref[...],
         (((2,), (1,)), ((0,), (0,))),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32)
 
     @pl.when(j == 0)
@@ -86,8 +90,9 @@ def _sparse_kernel(*refs, mode: str, alpha: float, beta: float,
         w = _ipow(tau_p, alpha) * _ipow(eta_ref[...], beta)
         mask = (av_ref[...] == 0).astype(w.dtype)
         v = _transform(w, mask, ar_ref[...], mode)
-        pos_ref[...] = jnp.argmax(v, axis=1).astype(jnp.int32)
-        have_ref[...] = ((w * mask).sum(axis=1) > 0).astype(jnp.int32)
+        pos_ref[...] = first_arg(v)[1]
+        have_ref[...] = ((w * mask).sum(axis=1, keepdims=True) > 0
+                         ).astype(jnp.int32)
 
 
 @functools.partial(
@@ -102,7 +107,8 @@ def sparse_select(tau_rows: jax.Array, eta_rows: jax.Array,
                   tau_scale: jax.Array | None = None,
                   block_m: int = DEFAULT_BLOCK_M,
                   block_n: int = DEFAULT_BLOCK_N,
-                  interpret: bool = True) -> tuple[jax.Array, jax.Array]:
+                  interpret: bool | None = None
+                  ) -> tuple[jax.Array, jax.Array]:
     """tau_rows/eta_rows (m, K) f32; cand (m, K) i32 candidate city ids;
     visited (m, n) bool/int8; rand (m, n) f32.
 
@@ -163,17 +169,17 @@ def sparse_select(tau_rows: jax.Array, eta_rows: jax.Array,
         grid=(gm, gn),
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((bm,), lambda i, j: (i,)),        # pos
-            pl.BlockSpec((bm,), lambda i, j: (i,)),        # have
+            pl.BlockSpec((bm, 1), lambda i, j: (i, 0)),    # pos
+            pl.BlockSpec((bm, 1), lambda i, j: (i, 0)),    # have
             pl.BlockSpec((bm, kk), lambda i, j: (i, 0)),   # vis accumulator
             pl.BlockSpec((bm, kk), lambda i, j: (i, 0)),   # rand accumulator
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((mp,), jnp.int32),
-            jax.ShapeDtypeStruct((mp,), jnp.int32),
+            jax.ShapeDtypeStruct((mp, 1), jnp.int32),
+            jax.ShapeDtypeStruct((mp, 1), jnp.int32),
             jax.ShapeDtypeStruct((mp, kk), jnp.float32),
             jax.ShapeDtypeStruct((mp, kk), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(*operands)
-    return pos[:m], have[:m]
+    return pos[:m, 0], have[:m, 0]

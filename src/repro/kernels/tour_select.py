@@ -20,10 +20,24 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from . import resolve_interpret
+
 DEFAULT_BLOCK_M = 8
 DEFAULT_BLOCK_N = 512
 
 _NEG_INF = -1e30
+
+
+def first_arg(v, largest: bool = True):
+    """Row-wise (max, index of its first occurrence) of a 2-D tile, both
+    (rows, 1); ``largest=False`` gives the min.  Spelled out instead of
+    jnp.argmax: the kernels must break ties on the first index, as XLA's
+    argmax does, whatever the TPU's own index reduction does."""
+    best = (jnp.max if largest else jnp.min)(v, axis=1, keepdims=True)
+    lanes = jax.lax.broadcasted_iota(jnp.int32, v.shape, 1)
+    first = jnp.min(jnp.where(v == best, lanes, v.shape[1]), axis=1,
+                    keepdims=True)
+    return best, first
 
 
 def _transform(rows, mask, rand, mode: str):
@@ -45,13 +59,13 @@ def _select_kernel(rows_ref, vis_ref, rand_ref, nact_ref, val_ref, idx_ref,
     j = pl.program_id(1)
     rows = rows_ref[...]
     cols = j * block_n + jax.lax.broadcasted_iota(jnp.int32, rows.shape, 1)
-    mask = ((vis_ref[...] == 0) & (cols < nact_ref[0, 0])).astype(rows.dtype)
+    vis = vis_ref[...].astype(jnp.int32)    # v5e has no int8 vector compare
+    mask = ((vis == 0) & (cols < nact_ref[0, 0])).astype(rows.dtype)
     rand = rand_ref[...]
     v = _transform(rows, mask, rand, mode)          # (bm, bn)
 
-    tile_val = jnp.max(v, axis=1)                    # (bm,)
-    local = jnp.argmax(v, axis=1).astype(jnp.int32)  # first-max within tile
-    tile_idx = local + j * block_n
+    tile_val, local = first_arg(v)                  # (bm, 1) each
+    tile_idx = local + j * block_n                  # first-max within tile
 
     @pl.when(j == 0)
     def _init():
@@ -75,7 +89,7 @@ def tour_select(rows: jax.Array, visited: jax.Array, rand: jax.Array,
                 n_actual: jax.Array | None = None,
                 block_m: int = DEFAULT_BLOCK_M,
                 block_n: int = DEFAULT_BLOCK_N,
-                interpret: bool = True) -> jax.Array:
+                interpret: bool | None = None) -> jax.Array:
     """rows/visited/rand: (m, n). Returns (m,) int32 selected city per ant.
 
     ``n_actual``: optional traced () scalar; cities >= n_actual (the
@@ -110,14 +124,14 @@ def tour_select(rows: jax.Array, visited: jax.Array, rand: jax.Array,
             pl.BlockSpec((1, 1), lambda i, j: (0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((bm,), lambda i, j: (i,)),
-            pl.BlockSpec((bm,), lambda i, j: (i,)),
+            pl.BlockSpec((bm, 1), lambda i, j: (i, 0)),
+            pl.BlockSpec((bm, 1), lambda i, j: (i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((mp,), rows.dtype),
-            jax.ShapeDtypeStruct((mp,), jnp.int32),
+            jax.ShapeDtypeStruct((mp, 1), rows.dtype),
+            jax.ShapeDtypeStruct((mp, 1), jnp.int32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(rows, visited, rand, n_act)
     del val
-    return idx[:m]
+    return idx[:m, 0]

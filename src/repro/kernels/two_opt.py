@@ -38,6 +38,9 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from . import resolve_interpret
+from .tour_select import first_arg
+
 DEFAULT_BLOCK_M = 8
 DEFAULT_BLOCK_N = 512
 
@@ -49,20 +52,20 @@ def _delta_kernel(a1_ref, a2_ref, r1_ref, r2_ref, valid_ref,
                   val_ref, idx_ref, *, mode: str, thr: float, block_n: int):
     j = pl.program_id(1)
     delta = a1_ref[...] + a2_ref[...] - r1_ref[...] - r2_ref[...]
-    ok = valid_ref[...] != 0
+    ok = valid_ref[...].astype(jnp.int32) != 0   # no int8 compare on v5e
 
     if mode == "best":
         v = jnp.where(ok, delta, _INF)
-        tile_val = jnp.min(v, axis=1)
-        local = jnp.argmin(v, axis=1).astype(jnp.int32)
+        tile_val, local = first_arg(v, largest=False)             # (bm, 1)
         tile_idx = local + j * block_n
     elif mode == "first":
         imp = ok & (delta < -thr)
-        has = jnp.any(imp, axis=1)
-        local = jnp.argmax(imp, axis=1).astype(jnp.int32)
+        has = jnp.any(imp, axis=1, keepdims=True)
+        local = first_arg(imp.astype(jnp.float32))[1]   # first True
         # delta at the local winner, via one-hot select (TPU-safe gather)
         lanes = jax.lax.broadcasted_iota(jnp.int32, delta.shape, 1)
-        dsel = jnp.sum(jnp.where(lanes == local[:, None], delta, 0.0), axis=1)
+        dsel = jnp.sum(jnp.where(lanes == local, delta, 0.0), axis=1,
+                       keepdims=True)
         tile_val = jnp.where(has, dsel, _INF)
         tile_idx = jnp.where(has, local + j * block_n, _IMAX)
     else:
@@ -93,7 +96,8 @@ def two_opt_best(add1: jax.Array, add2: jax.Array, rem1: jax.Array,
                  rem2: jax.Array, valid: jax.Array, thr: float = 0.0,
                  mode: str = "best", block_m: int = DEFAULT_BLOCK_M,
                  block_n: int = DEFAULT_BLOCK_N,
-                 interpret: bool = True) -> tuple[jax.Array, jax.Array]:
+                 interpret: bool | None = None
+                 ) -> tuple[jax.Array, jax.Array]:
     """Operands (m, M) f32 (+ valid mask); returns ((m,) delta, (m,) idx).
 
     ``best``: (min masked delta, its first flat index); delta is +inf when
@@ -115,17 +119,17 @@ def two_opt_best(add1: jax.Array, add2: jax.Array, rem1: jax.Array,
     mp, Mp = add1.shape
     gm, gn = mp // bm, Mp // bn
     spec = pl.BlockSpec((bm, bn), lambda i, j: (i, j))
-    out_spec = pl.BlockSpec((bm,), lambda i, j: (i,))
+    out_spec = pl.BlockSpec((bm, 1), lambda i, j: (i, 0))
     val, idx = pl.pallas_call(
         functools.partial(_delta_kernel, mode=mode, thr=thr, block_n=bn),
         grid=(gm, gn),
         in_specs=[spec, spec, spec, spec, spec],
         out_specs=[out_spec, out_spec],
         out_shape=[
-            jax.ShapeDtypeStruct((mp,), jnp.float32),
-            jax.ShapeDtypeStruct((mp,), jnp.int32),
+            jax.ShapeDtypeStruct((mp, 1), jnp.float32),
+            jax.ShapeDtypeStruct((mp, 1), jnp.int32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(add1.astype(jnp.float32), add2.astype(jnp.float32),
       rem1.astype(jnp.float32), rem2.astype(jnp.float32), valid)
-    return val[:m], idx[:m]
+    return val[:m, 0], idx[:m, 0]

@@ -28,7 +28,9 @@ from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
 
 from repro.analysis import hlo as ha                  # noqa: E402
 from repro.core import aco, islands                   # noqa: E402
-from repro.launch.mesh import HW, make_production_mesh  # noqa: E402
+from repro.launch.mesh import make_production_mesh, peaks  # noqa: E402
+
+HW = peaks("TPU v5 lite")   # the production pods this dry run models
 
 
 def lower_aco(n: int, variant: str, multi_pod: bool) -> dict:

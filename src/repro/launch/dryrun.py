@@ -30,10 +30,12 @@ from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
 from repro import configs              # noqa: E402
 from repro.analysis import hlo as ha   # noqa: E402
 from repro.launch import specs as sp   # noqa: E402
-from repro.launch.mesh import HW, make_production_mesh  # noqa: E402
+from repro.launch.mesh import make_production_mesh, peaks  # noqa: E402
 from repro.launch import steps as st   # noqa: E402
 from repro.models import sharding as sh  # noqa: E402
 from repro.optim import adamw          # noqa: E402
+
+HW = peaks("TPU v5 lite")   # the production pods this dry run models
 
 
 def model_flops(cfg, cell: sp.ShapeCell) -> float:

@@ -40,9 +40,24 @@ def make_mesh_for(devices: int | None = None, model_parallel: int = 1,
     return jax.make_mesh((dp, model_parallel), ("data", "model"))
 
 
-# TPU v5e-flavoured hardware constants for the roofline analysis.
-HW = {
-    "peak_flops_bf16": 197e12,     # per chip
-    "hbm_bw": 819e9,               # bytes/s per chip
-    "ici_bw": 50e9,                # bytes/s per link
+# Published per-chip peaks, keyed by ``jax.Device.device_kind``.  Source:
+# Google Cloud documentation, "TPU v5e" (197 TFLOP/s bf16, 393 TOP/s int8,
+# 16 GB HBM at 819 GB/s, 1,600 Gbit/s of interconnect over 4 links).
+PEAKS = {
+    "TPU v5 lite": {
+        "peak_flops_bf16": 197e12,     # per chip
+        "peak_ops_int8": 393e12,       # per chip
+        "hbm_bw": 819e9,               # bytes/s per chip
+        "ici_bw": 50e9,                # bytes/s per link
+    },
 }
+
+
+def peaks(device_kind: str) -> dict:
+    """Peaks of one chip of ``device_kind``; a kind not in ``PEAKS`` is an
+    error, never a default."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(f"no published peaks for device kind "
+                         f"{device_kind!r}; known: {sorted(PEAKS)}") from None

@@ -56,20 +56,22 @@ solution quality stays within 1% absolute of fp32 (benchmarks/quality
 
 AOT program cache (DESIGN.md §16): ``--warmup`` pre-compiles the bucket
 ladder for the [min_n, max_n] range before traffic (``--warmup-async``
-on a background thread; ``--bucket-ladder 16,32`` overrides the rungs),
-``--cache-dir`` enables the persistent XLA compilation cache so a
-restart pays a cache load instead of a compile, and ``--dry`` compiles
-the ladder, prints the program/cache stats as JSON and exits (the CI
-smoke).  ``--draw-mode counter --ants M`` makes the randomness
+on a background thread; ``--bucket-ladder 16,32`` overrides the rungs;
+a foreground warmup that fails to compile a bucket exits 1), and
+``--dry`` compiles the ladder, prints the program/cache stats as JSON
+and exits (the CI smoke).  The persistent XLA compilation cache is
+always on, so a restart pays a cache load instead of a compile: it lives
+in ``JAX_COMPILATION_CACHE_DIR`` when that is set (``--cache-dir`` is
+then ignored), else in ``--cache-dir``, else in ``<checkout>/.jax_cache``.
+``--draw-mode counter --ants M`` makes the randomness
 bucket-width invariant, which lets admission neighbour-route an
 unwarmed bucket into the nearest larger warmed one bitwise-exactly:
 
     PYTHONPATH=src python -m repro.launch.solve_serve --warmup \\
-        --cache-dir /tmp/xla-cache --num-instances 8 --iterations 20
+        --num-instances 8 --iterations 20
     PYTHONPATH=src python -m repro.launch.solve_serve --stream --warmup \\
         --warmup-async --draw-mode counter --ants 32 --num-instances 8
-    PYTHONPATH=src python -m repro.launch.solve_serve --warmup --dry \\
-        --cache-dir /tmp/xla-cache
+    PYTHONPATH=src python -m repro.launch.solve_serve --warmup --dry
 
 CPU-scale usage:
     PYTHONPATH=src python -m repro.launch.solve_serve \
@@ -98,9 +100,9 @@ from repro.core import aco, tsp
 from repro.kernels.ops import UnsupportedKernelRoute
 from repro.launch.mesh import make_data_mesh
 from repro.solver import (ProgramCache, SolverService,
-                          StreamingSolverService, enable_persistent_cache,
-                          make_poisson_trace, persistent_cache_stats,
-                          replay_trace)
+                          StreamingSolverService, compile_cache_dir,
+                          enable_persistent_cache, make_poisson_trace,
+                          persistent_cache_stats, replay_trace)
 
 
 def make_workload(num: int, min_n: int, max_n: int, seed: int):
@@ -179,7 +181,10 @@ def _hold_endpoint(args, server) -> None:
         time.sleep(args.metrics_hold)
 
 
-def main() -> None:
+def main(argv: list[str] | None = None):
+    """Run the CLI over ``argv`` (default ``sys.argv[1:]``).  Returns
+    ``(service, results)`` so in-process callers can inspect both; None
+    after ``--dry``."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--num-instances", type=int, default=8)
     ap.add_argument("--min-n", type=int, default=12)
@@ -287,10 +292,11 @@ def main() -> None:
                          "admitted immediately and falls back to the jit "
                          "path until each bucket's compile lands")
     ap.add_argument("--cache-dir", default=None,
-                    help="persistent XLA compilation cache directory: "
-                         "compiled executables survive restarts, so the "
-                         "second cold start pays a cache load, not a "
-                         "compile")
+                    help="persistent XLA compilation cache directory "
+                         "(default <checkout>/.jax_cache; ignored when "
+                         "JAX_COMPILATION_CACHE_DIR is set): compiled "
+                         "executables survive restarts, so the second "
+                         "cold start pays a cache load, not a compile")
     ap.add_argument("--bucket-ladder", default=None,
                     help="--warmup: explicit comma-separated bucket list "
                          "(default: batch.bucket_ladder over "
@@ -308,7 +314,7 @@ def main() -> None:
     ap.add_argument("--ants", type=int, default=None,
                     help="pin the ant count (default: m = n_pad); "
                          "required for neighbour-bucket routing")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
     cfg = aco.ACOConfig(iterations=args.iterations, variant=args.variant,
                         selection=args.selection,
@@ -327,8 +333,7 @@ def main() -> None:
 
     if args.dry and not args.warmup:
         ap.error("--dry requires --warmup")
-    if args.cache_dir:
-        enable_persistent_cache(args.cache_dir)
+    cache_dir = enable_persistent_cache(compile_cache_dir(args.cache_dir))
     programs = ProgramCache(telemetry=tel) if args.warmup else None
     ladder = ([int(x) for x in args.bucket_ladder.split(",")]
               if args.bucket_ladder else None)
@@ -343,6 +348,10 @@ def main() -> None:
                                     background=args.warmup_async
                                     and not args.dry)
         warm_s = time.perf_counter() - t0
+        if isinstance(summary, dict) and summary["errors"]:
+            for err in summary["errors"]:
+                print(f"solve_serve: warmup error {err}", file=sys.stderr)
+            sys.exit(1)
         if not args.dry:
             print(f"solve_serve: warmup "
                   f"{'started (background)' if args.warmup_async else f'done in {warm_s:.2f}s'}",
@@ -353,9 +362,8 @@ def main() -> None:
             "dry": True,
             "warmup": summary,
             "stats": {"programs": programs.stats()},
+            "cache": persistent_cache_stats(cache_dir),
         }
-        if args.cache_dir:
-            report["cache"] = persistent_cache_stats(args.cache_dir)
         print(json.dumps(_round(report), indent=2), flush=True)
         return True
 
@@ -374,7 +382,7 @@ def main() -> None:
                 programs=programs)
             server = _start_metrics_server(args, tel, svc)
             if _warm(svc):
-                return
+                return None
             trace = make_poisson_trace(args.num_instances, args.arrival_rate,
                                        args.min_n, args.max_n,
                                        seed=args.seed,
@@ -393,7 +401,7 @@ def main() -> None:
                                 programs=programs)
             server = _start_metrics_server(args, tel, svc)
             if _warm(svc):
-                return
+                return None
             for i, inst in enumerate(make_workload(
                     args.num_instances, args.min_n, args.max_n, args.seed)):
                 svc.submit(inst, tenant=(tenants[i % len(tenants)]
@@ -407,6 +415,7 @@ def main() -> None:
         # hold last: the report and exports are already on disk, so the
         # external scraper can kill us whenever it has what it needs
         _hold_endpoint(args, server)
+        return svc, results
     except UnsupportedKernelRoute as e:
         # one actionable line instead of a traceback (DESIGN.md §10/§12:
         # the route checker's message already says which flag to drop)

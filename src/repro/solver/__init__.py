@@ -27,8 +27,8 @@ from .batch import (ProblemBatch, bucket_ladder, bucket_size,  # noqa: F401
 from .engine import (init_state, init_states, run_batch,  # noqa: F401
                      solve_instances)
 from .programs import (ProgramCache, ProgramKey,  # noqa: F401
-                       check_neighbour_route, enable_persistent_cache,
-                       persistent_cache_stats)
+                       check_neighbour_route, compile_cache_dir,
+                       enable_persistent_cache, persistent_cache_stats)
 from .placement import data_mesh, run_batch_sharded  # noqa: F401
 from .service import SolveResult, SolverService  # noqa: F401
 from .streaming import (AdmissionError, StreamingPool,  # noqa: F401

@@ -8,17 +8,18 @@ cold-start problem ROADMAP names (aphrodite pre-captures CUDA graphs at
 it on three layers (DESIGN.md §16):
 
 1. **Persistent compilation cache** — ``enable_persistent_cache`` points
-   JAX's executable cache at a directory, so compiled programs survive
-   process restarts: the second cold start of the same service pays a
-   cache *load*, not a compile.
+   JAX's executable cache at the directory ``compile_cache_dir`` picks
+   (``JAX_COMPILATION_CACHE_DIR`` when set, else ``<checkout>/.jax_cache``),
+   so compiled programs survive process restarts: the second cold start of
+   the same service pays a cache *load*, not a compile.
 2. **Warmup ladder** — ``ProgramCache.warm`` AOT-lowers-and-compiles the
    engine program for every bucket of ``batch.bucket_ladder`` before the
    service accepts traffic (optionally on a background thread), holding
    the compiled executables for direct dispatch.  ``engine.run_batch``
    routes through ``ProgramCache.call``: a warmed signature dispatches the
-   AOT executable (``jit_cache_hit``), anything else falls back to the
+   AOT executable (``jit_cache_hit``), any other signature takes the
    ordinary jit path (``jit_cache_miss``) and compiles on demand exactly
-   as before.
+   as before.  A warmed executable that refuses its operands raises.
 3. **Neighbour-bucket routing** — ``route_bucket`` pads a request whose
    native bucket is *not* warmed into the nearest larger warmed bucket
    instead of blocking the stream on a compile.  Exactness contract: the
@@ -34,6 +35,7 @@ it on three layers (DESIGN.md §16):
 from __future__ import annotations
 
 import os
+import sys
 import threading
 import time
 from typing import NamedTuple, Optional, Sequence
@@ -80,6 +82,28 @@ class ProgramKey(NamedTuple):
 
 # ------------------------------------------------- persistent XLA cache
 
+# A fixed path: the cache key holds the directory, so a path that moves
+# between runs (temp, pid, time) never hits.
+DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))), ".jax_cache")
+
+
+def compile_cache_dir(requested: Optional[str] = None) -> str:
+    """The persistent compilation cache's directory.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, is placed from outside and
+    wins: ``requested`` is then ignored, with a one-line note on stderr.
+    Otherwise ``requested``, else the fixed ``<checkout>/.jax_cache``."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        if requested:
+            print(f"programs: JAX_COMPILATION_CACHE_DIR={env} is set; "
+                  f"ignoring cache dir {requested}", file=sys.stderr)
+        return env
+    return requested or DEFAULT_CACHE_DIR
+
+
 def enable_persistent_cache(cache_dir: str) -> str:
     """Point JAX's persistent compilation cache at ``cache_dir``.
 
@@ -91,6 +115,7 @@ def enable_persistent_cache(cache_dir: str) -> str:
     """
     cache_dir = os.path.abspath(cache_dir)
     os.makedirs(cache_dir, exist_ok=True)
+    jax.config.update("jax_enable_compilation_cache", True)
     jax.config.update("jax_compilation_cache_dir", cache_dir)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
@@ -169,7 +194,7 @@ class ProgramCache:
     over a bucket ladder, ``call`` is the hot path ``engine.run_batch``
     routes through, ``route_bucket`` is the admission-time neighbour
     lookup.  Thread-safe: the warmup may run on a background thread while
-    the service admits traffic (misses fall back to the jit path, so a
+    the service admits traffic (misses take the jit path, so a
     half-warmed ladder is never wrong, only slower).
 
     ``iters_cap``: warmed programs are compiled with this ``max_iters``
@@ -346,8 +371,8 @@ class ProgramCache:
                         b, batch, cfg, max_iters, patience, donate,
                         kind=kind, hyper=hyper)
             except Exception as e:            # noqa: BLE001 — background
-                # thread must not die silently; the bucket stays cold and
-                # serve-time falls back to the jit path.
+                # thread must not die silently: the bucket stays cold and
+                # the error is reported in the summary and ``stats()``.
                 with self._lock:
                     self._warm_errors.append(f"b{b}: {type(e).__name__}: {e}")
                 self.tel.events.emit("warmup_error", bucket=b,
@@ -397,22 +422,18 @@ class ProgramCache:
         """Dispatch one ``run_batch`` call: AOT executable on a warmed
         signature (``jit_cache_hit``), the ordinary jit path otherwise
         (``jit_cache_miss`` — jax compiles and caches on first sight, so
-        a missed signature costs one compile, exactly as before)."""
+        a missed signature costs one compile, exactly as before).  A
+        warmed executable that refuses its operands (layout or sharding
+        drift) raises: re-running the request on the jit path would hide
+        a recompile on every call."""
         key = self.signature(problem, states, budgets, cfg, max_iters,
                              patience, donate, kind, ewt)
         with self._lock:
             compiled = self._programs.get(key)
         if compiled is not None:
-            try:
-                out = compiled(problem, states, budgets, since, mets)
-                self._c_hit.inc()
-                return out
-            except Exception as e:            # noqa: BLE001 — an AOT
-                # dispatch mismatch (layout/sharding drift) must degrade
-                # to the jit path, not fail the request.
-                self.tel.events.emit(
-                    "aot_dispatch_fallback", bucket=key.n_pad,
-                    batch=key.batch, error=f"{type(e).__name__}: {e}")
+            out = compiled(problem, states, budgets, since, mets)
+            self._c_hit.inc()
+            return out
         self._c_miss.inc()
         self._note_miss(key)
         return fn(problem, states, budgets, cfg, max_iters, patience,

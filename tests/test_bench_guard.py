@@ -134,3 +134,22 @@ def test_regress_flags_regression_in_fresh_payload(tmp_path, monkeypatch):
     monkeypatch.setitem(regress.RUNNERS, "streaming", fake_runner)
     assert regress.run_checks(["streaming"], dry=False,
                               tol_scale=1.0, root=root) == 1
+
+
+def test_run_parent_never_imports_jax():
+    """benchmarks/run.py runs each table in its own process, and must not
+    hold a device itself: importing it (and its manifest helper) leaves
+    JAX unimported, and every table maps to a module of this package."""
+    import subprocess
+    import sys
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    code = ("import sys\n"
+            "from benchmarks import run\n"
+            "assert 'jax' not in sys.modules, 'run.py imported jax'\n"
+            "import os\n"
+            "for module, _, _ in run.TABLES.values():\n"
+            "    assert os.path.isfile(os.path.join('benchmarks', "
+            "module + '.py')), module\n")
+    out = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                         text=True, cwd=root, timeout=120)
+    assert out.returncode == 0, out.stderr

@@ -441,6 +441,52 @@ def test_persistent_cache_populates_and_reuses(tmp_path):
     assert runs[1] == runs[0]          # second run loaded, didn't re-write
 
 
+def test_compile_cache_dir_env_wins(monkeypatch, capsys):
+    """JAX_COMPILATION_CACHE_DIR, when set, is the directory (a requested
+    one is ignored with a note); otherwise the requested directory, else
+    the fixed checkout path."""
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/placed/outside")
+    assert programs.compile_cache_dir() == "/placed/outside"
+    assert programs.compile_cache_dir("elsewhere") == "/placed/outside"
+    assert "ignoring cache dir elsewhere" in capsys.readouterr().err
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    assert programs.compile_cache_dir() == os.path.join(root, ".jax_cache")
+    assert programs.compile_cache_dir("elsewhere") == "elsewhere"
+
+
+def test_solve_serve_writes_cache_only_to_env_dir(tmp_path):
+    """A solve_serve run under JAX_COMPILATION_CACHE_DIR writes its
+    compiled executables there, and a requested --cache-dir stays empty."""
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    placed, requested = tmp_path / "placed", tmp_path / "requested"
+    env = dict(os.environ, PYTHONPATH=os.path.join(root, "src"),
+               JAX_COMPILATION_CACHE_DIR=str(placed))
+    out = subprocess.run(
+        [sys.executable, "-m", "repro.launch.solve_serve", "--warmup",
+         "--dry", "--min-n", "12", "--max-n", "12", "--iterations", "2",
+         "--cache-dir", str(requested)],
+        capture_output=True, text=True, env=env, cwd=root, timeout=300)
+    assert out.returncode == 0, out.stderr
+    assert f"ignoring cache dir {requested}" in out.stderr
+    assert programs.persistent_cache_stats(str(placed))["files"] > 0
+    assert not requested.exists()
+
+
+def test_foreground_warmup_error_exits_nonzero():
+    """A bucket that fails to compile makes a foreground --warmup exit 1
+    and names the bucket on stderr, instead of serving it cold."""
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, PYTHONPATH=os.path.join(root, "src"))
+    out = subprocess.run(
+        [sys.executable, "-m", "repro.launch.solve_serve", "--warmup",
+         "--dry", "--bucket-ladder", "0", "--min-n", "12", "--max-n", "12",
+         "--iterations", "2"],
+        capture_output=True, text=True, env=env, cwd=root, timeout=300)
+    assert out.returncode == 1
+    assert "solve_serve: warmup error b0" in out.stderr
+
+
 def test_persistent_cache_stats_missing_dir():
     st = programs.persistent_cache_stats("/nonexistent/xla-cache")
     assert st["files"] == 0 and st["bytes"] == 0

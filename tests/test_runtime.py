@@ -82,3 +82,13 @@ def test_deadline_triggers_restart_path(tmp_path):
     out = sup.run()
     assert sup.restarts == 1
     assert int(out.iteration) == 6
+
+
+def test_peaks_keyed_by_device_kind():
+    """Roofline peaks come from a table keyed by device_kind; a kind the
+    table does not hold is an error, not a default."""
+    from repro.launch import mesh
+    v5e = mesh.peaks("TPU v5 lite")
+    assert v5e["peak_flops_bf16"] == 197e12 and v5e["hbm_bw"] == 819e9
+    with pytest.raises(ValueError, match="no published peaks"):
+        mesh.peaks("cpu")
