@@ -302,6 +302,11 @@ def colony_step(problem: Problem, state: ColonyState,
         # precompute on this route at all.
         method = "fused"
 
+    # Named scopes (choice, construct, local_search, deposit) put each
+    # phase's name in the metadata of every device operation it lowers
+    # to, Pallas kernels included, so a profiler capture attributes
+    # device time to the phases of the step.  Metadata only: the program
+    # and its results are the same without them.
     tau_c, tau_scale = tau_full, None
     if method == "fused":
         choice_info = jnp.zeros((1, 1), jnp.float32)   # unused by the step
@@ -312,16 +317,18 @@ def colony_step(problem: Problem, state: ColonyState,
             tau_c = state.tau.q
             tau_scale = state.tau.scale if cfg.tau_dtype == "int8" else None
     else:
-        choice_info = _choice(tau_full, problem.eta, cfg, alpha, beta,
-                              n_act)
+        with jax.named_scope("choice"):
+            choice_info = _choice(tau_full, problem.eta, cfg, alpha, beta,
+                                  n_act)
 
-    res = strategies.construct_tours(
-        k_tour, problem.dist, choice_info, m,
-        method=method, selection=cfg.selection,
-        nn=problem.nn, tau=tau_c, eta=problem.eta,
-        alpha=alpha, beta=beta, n_actual=n_act,
-        tau_scale=tau_scale, draw_mode=cfg.draw_mode,
-    )
+    with jax.named_scope("construct"):
+        res = strategies.construct_tours(
+            k_tour, problem.dist, choice_info, m,
+            method=method, selection=cfg.selection,
+            nn=problem.nn, tau=tau_c, eta=problem.eta,
+            alpha=alpha, beta=beta, n_actual=n_act,
+            tau_scale=tau_scale, draw_mode=cfg.draw_mode,
+        )
 
     pre_ls_lengths = None
     if cfg.local_search != "none":
@@ -329,7 +336,8 @@ def colony_step(problem: Problem, state: ColonyState,
         # and before the pheromone update (DESIGN.md §7).
         if cfg.metrics:
             pre_ls_lengths = res.lengths    # acceptance-rate baseline
-        res = _apply_local_search(problem, res, state.iteration, cfg)
+        with jax.named_scope("local_search"):
+            res = _apply_local_search(problem, res, state.iteration, cfg)
 
     it_best_idx = jnp.argmin(res.lengths)
     it_best_len = res.lengths[it_best_idx]
@@ -339,59 +347,60 @@ def colony_step(problem: Problem, state: ColonyState,
     best_len = jnp.where(improved, it_best_len, state.best_len)
     best_tour = jnp.where(improved, it_best_tour, state.best_tour)
 
-    if cfg.variant == "as":
-        dep_tours, dep_w = res.tours, q / res.lengths
-    elif cfg.variant == "mmas":
-        if cfg.mmas_best == "global":
+    with jax.named_scope("deposit"):
+        if cfg.variant == "as":
+            dep_tours, dep_w = res.tours, q / res.lengths
+        elif cfg.variant == "mmas":
+            if cfg.mmas_best == "global":
+                dep_tours = best_tour[None, :]
+                dep_w = (q / best_len)[None]
+            else:
+                dep_tours = it_best_tour[None, :]
+                dep_w = (q / it_best_len)[None]
+        elif cfg.variant == "acs":
             dep_tours = best_tour[None, :]
-            dep_w = (q / best_len)[None]
+            dep_w = (rho * q / best_len)[None]
         else:
-            dep_tours = it_best_tour[None, :]
-            dep_w = (q / it_best_len)[None]
-    elif cfg.variant == "acs":
-        dep_tours = best_tour[None, :]
-        dep_w = (rho * q / best_len)[None]
-    else:
-        raise ValueError(f"unknown variant {cfg.variant}")
+            raise ValueError(f"unknown variant {cfg.variant}")
 
-    if cfg.use_pallas:
-        from repro.kernels import ops as kops
-        tau = kops.pheromone_update(tau_full, dep_tours, dep_w, rho,
-                                    n_actual=n_act)
-    else:
-        tau = pheromone.update(tau_full, dep_tours, dep_w, rho,
-                               strategy=cfg.deposit, tile=cfg.deposit_tile,
-                               n_actual=n_act)
+        if cfg.use_pallas:
+            from repro.kernels import ops as kops
+            tau = kops.pheromone_update(tau_full, dep_tours, dep_w, rho,
+                                        n_actual=n_act)
+        else:
+            tau = pheromone.update(tau_full, dep_tours, dep_w, rho,
+                                   strategy=cfg.deposit, tile=cfg.deposit_tile,
+                                   n_actual=n_act)
 
-    # MMAS/ACS normalisations use the real city count of padded instances.
-    n_eff = n if n_act is None else n_act
-    clamp = None
-    if cfg.variant == "mmas":
-        tau_max = q / (rho * best_len)
-        tau_min = tau_max / (2.0 * n_eff)
-        tau = jnp.clip(tau, tau_min, tau_max)
-        clamp = (tau_min, tau_max)
-    elif cfg.variant == "acs":
-        # Parallel-ACS local rule: decay edges crossed this iteration.
-        f, t = pheromone.tour_edges(res.tours, n_act)
-        tau0 = q / (n_eff * jnp.maximum(best_len, 1e-9))
-        ew = None
-        if n_act is not None:
-            # phantom-tail crossings must not decay (multiplicity 0)
-            idx = jnp.arange(n, dtype=jnp.int32)
-            ew = jnp.broadcast_to((idx < n_act).astype(tau.dtype),
-                                  res.tours.shape).ravel()
-        tau = pheromone.local_update_acs(tau, f.ravel(), t.ravel(), cfg.xi,
-                                         tau0, w=ew)
+        # MMAS/ACS normalisations use the real city count of padded instances.
+        n_eff = n if n_act is None else n_act
+        clamp = None
+        if cfg.variant == "mmas":
+            tau_max = q / (rho * best_len)
+            tau_min = tau_max / (2.0 * n_eff)
+            tau = jnp.clip(tau, tau_min, tau_max)
+            clamp = (tau_min, tau_max)
+        elif cfg.variant == "acs":
+            # Parallel-ACS local rule: decay edges crossed this iteration.
+            f, t = pheromone.tour_edges(res.tours, n_act)
+            tau0 = q / (n_eff * jnp.maximum(best_len, 1e-9))
+            ew = None
+            if n_act is not None:
+                # phantom-tail crossings must not decay (multiplicity 0)
+                idx = jnp.arange(n, dtype=jnp.int32)
+                ew = jnp.broadcast_to((idx < n_act).astype(tau.dtype),
+                                      res.tours.shape).ravel()
+            tau = pheromone.local_update_acs(tau, f.ravel(), t.ravel(), cfg.xi,
+                                             tau0, w=ew)
 
-    # Quantise-on-store (quant.py): the fp32 result of this step's update
-    # becomes the next resident payload; metrics below read the exact fp32
-    # tau this step computed, before the store rounds it.
-    tau_store = tau
-    if quantised:
-        tau_store = quant.requantise(
-            tau, state.tau, cfg.tau_dtype,
-            quant.round_key(cfg.tau_round, k_q))
+        # Quantise-on-store (quant.py): the fp32 result of this step's update
+        # becomes the next resident payload; metrics below read the exact fp32
+        # tau this step computed, before the store rounds it.
+        tau_store = tau
+        if quantised:
+            tau_store = quant.requantise(
+                tau, state.tau, cfg.tau_dtype,
+                quant.round_key(cfg.tau_round, k_q))
 
     new_state = ColonyState(tau_store, best_tour, best_len,
                             state.iteration + 1, key)

@@ -8,11 +8,14 @@ Three layers:
 2. **Host-side spans + events** (``registry.Registry``, ``trace.Tracer``,
    ``trace.EventLog``): counters/gauges/bounded histograms the services'
    ``stats()`` read from, wall-clock spans on per-device/per-bucket
-   tracks, and a JSON-lines slot-lifecycle event log.
+   tracks, and a JSON-lines slot-lifecycle event log.  The colony step
+   names its phases with ``jax.named_scope`` (``choice``, ``construct``,
+   ``local_search``, ``deposit``) in every device operation's metadata.
 3. **Export surfaces**: Chrome-trace (Perfetto-loadable) timelines,
-   ``repro.obs/v1`` metrics snapshots, and ``jax.profiler`` hooks —
-   surfaced by ``launch.solve_serve --metrics-out/--trace-out/
-   --events-out``.
+   ``repro.obs/v1`` metrics snapshots, and ``jax.profiler`` captures —
+   every live span also lands in a running capture as ``aco.<name>`` on
+   the device trace's clock — surfaced by ``launch.solve_serve
+   --metrics-out/--trace-out/--events-out/--jax-profile-dir``.
 4. **Serving plane** (``serving``, DESIGN.md §14): per-tenant SLO
    accounting (``SloTracker`` over labeled registry families), the
    Prometheus text renderer, and the ``MetricsServer`` background
@@ -50,10 +53,6 @@ class Telemetry:
         self._profiling = False
 
     # ------------------------------------------------------- jax.profiler
-    @property
-    def profiling(self) -> bool:
-        return self._profiling
-
     def profile_start(self) -> None:
         if self.jax_profile_dir and not self._profiling:
             trace.profile_start(self.jax_profile_dir)
@@ -63,11 +62,6 @@ class Telemetry:
         if self._profiling:
             trace.profile_stop()
             self._profiling = False
-
-    def step_annotation(self, name: str, **kw):
-        """StepTraceAnnotation around a chunk dispatch — only pays when a
-        profiler capture is actually running."""
-        return trace.step_annotation(name, enabled=self._profiling, **kw)
 
     # ------------------------------------------------------------ exports
     def snapshot(self, extra: Optional[dict] = None) -> dict:

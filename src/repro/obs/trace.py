@@ -2,12 +2,16 @@
 
 Two host-side recording surfaces (DESIGN.md §13):
 
-- ``Tracer`` — wall-clock spans and instants on named (process, thread)
-  tracks, exported as Chrome trace-event JSON (``{"traceEvents": [...]}``)
-  that loads directly in Perfetto / ``chrome://tracing``.  The solver
-  services map devices to processes and buckets / slots to threads, so a
-  streaming run renders as per-device tracks of chunk dispatches with one
-  span per resident request lifetime.
+- ``Tracer`` — wall-clock spans on named (process, thread) tracks,
+  exported as Chrome trace-event JSON (``{"traceEvents": [...]}``) that
+  loads directly in Perfetto / ``chrome://tracing``.  The solver services
+  map devices to processes and buckets / slots to threads, so a streaming
+  run renders as per-device tracks of chunk dispatches with one span per
+  resident request lifetime.  Every live span is also written to any
+  running ``jax.profiler`` capture as ``aco.<name>`` (with its scalar
+  args), on the profiler's host clock, the clock the device's operations
+  are placed on: a capture shows which host phase each device gap falls
+  in, whoever started the capture.
 - ``EventLog`` — append-only JSON-lines records (``{"t": ..., "kind": ...,
   ...}``) for the slot lifecycle (submit → admit → chunk → harvest/evict)
   and periodic stats snapshots; greppable and cheap to tail.
@@ -17,23 +21,31 @@ count, so a long-lived service cannot leak memory through its own
 observability (the same discipline registry.Histogram applies to
 latency samples).
 
-``jax.profiler`` hooks live here too: ``profile_start``/``profile_stop``
-wrap ``jax.profiler.start_trace``/``stop_trace`` and ``step_annotation``
-wraps ``StepTraceAnnotation`` so chunk steps show up as named steps in a
-TensorBoard/XPlane capture.  All jax imports are lazy — building a Tracer
-never touches device state.
+``profile_start``/``profile_stop`` wrap ``jax.profiler.start_trace``/
+``stop_trace`` (``solve_serve --jax-profile-dir``).  Importing jax here
+touches no device state.
 """
 from __future__ import annotations
 
 import json
 import time
 from collections import deque
-from contextlib import contextmanager
+from contextlib import contextmanager, nullcontext
 from typing import Optional
+
+import jax
+
+# Name prefix of the spans a live jax.profiler capture receives.
+PROFILER_PREFIX = "aco."
+
+
+def _scalars(args: dict) -> dict:
+    return {k: v for k, v in args.items()
+            if isinstance(v, (str, int, float))}
 
 
 class Tracer:
-    """Record spans/instants/counters on (process, thread) tracks."""
+    """Record spans on (process, thread) tracks."""
 
     def __init__(self, max_events: int = 200_000,
                  clock=time.perf_counter) -> None:
@@ -84,11 +96,30 @@ class Tracer:
     @contextmanager
     def span(self, name: str, process: str = "main", thread: str = "main",
              **args):
-        """Complete-event span ("X") covering the with-block wall time."""
+        """Complete-event span ("X") covering the with-block wall time.
+
+        Yields the span's args dict: the block may add args known only at
+        its end (a count of what it did), and they are recorded with it.
+
+        While a ``jax.profiler`` capture runs, the block is also a host
+        event ``aco.<name>`` in it, carrying the scalar args (ints, floats
+        and strings); list args such as ``request_ids`` stay in the Chrome
+        trace.  With no capture running the profiler sink costs one check
+        (as a ``TraceMe`` does, a span that starts before the capture is
+        not in it)."""
         pid, tid = self.track(process, thread)
+        live = jax.profiler.TraceAnnotation.is_enabled()
+        n = len(args)
+        ann = (jax.profiler.TraceAnnotation(PROFILER_PREFIX + name,
+                                            **_scalars(args))
+               if live else nullcontext())
         ts = self.now_us()
         try:
-            yield
+            with ann:
+                yield args
+                if live and len(args) > n:     # args the block added
+                    ann.set_metadata(**_scalars(dict(
+                        list(args.items())[n:])))
         finally:
             self._push({"ph": "X", "name": name, "pid": pid, "tid": tid,
                         "ts": ts, "dur": self.now_us() - ts,
@@ -101,19 +132,6 @@ class Tracer:
         pid, tid = self.track(process, thread)
         self._push({"ph": "X", "name": name, "pid": pid, "tid": tid,
                     "ts": ts_us, "dur": dur_us, "args": args})
-
-    def instant(self, name: str, process: str = "main",
-                thread: str = "main", **args) -> None:
-        pid, tid = self.track(process, thread)
-        self._push({"ph": "i", "s": "t", "name": name, "pid": pid,
-                    "tid": tid, "ts": self.now_us(), "args": args})
-
-    def counter(self, name: str, process: str = "main", **values) -> None:
-        """Chrome counter track ("C"): Perfetto renders it as a stacked
-        area chart (occupancy, queue depth)."""
-        pid, _ = self.track(process, "main")
-        self._push({"ph": "C", "name": name, "pid": pid, "tid": 0,
-                    "ts": self.now_us(), "args": values})
 
     def request_chain(self, request_id) -> list[dict]:
         """Recover one request's span chain (DESIGN.md §14): every event
@@ -170,23 +188,9 @@ class EventLog:
 # --------------------------------------------------------- jax.profiler
 def profile_start(log_dir: str) -> None:
     """Start a jax.profiler capture (XPlane/TensorBoard trace viewer)."""
-    import jax
     jax.profiler.start_trace(log_dir)
 
 
 def profile_stop() -> None:
-    import jax
     jax.profiler.stop_trace()
 
-
-@contextmanager
-def step_annotation(name: str, enabled: bool = True, **kw):
-    """Name the enclosed dispatches as one profiler step (chunk steps in
-    the streaming pool); a no-op passthrough when disabled so the hot path
-    pays nothing without a capture running."""
-    if not enabled:
-        yield
-        return
-    import jax
-    with jax.profiler.StepTraceAnnotation(name, **kw):
-        yield

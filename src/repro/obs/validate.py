@@ -21,8 +21,8 @@ from numbers import Number
 from typing import Iterable, Union
 
 # Chrome trace-event phases the Tracer emits (trace.py): M metadata, X
-# complete spans, i instants, C counter samples.
-KNOWN_PHASES = {"M", "X", "i", "C"}
+# complete spans.
+KNOWN_PHASES = {"M", "X"}
 
 # Event-log kinds that are about one specific request and therefore must
 # carry the request-scoped correlation fields.

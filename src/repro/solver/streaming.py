@@ -37,8 +37,11 @@ a ``Telemetry`` bundle — counters/gauges/**bounded** histograms behind
 ``stats`` (occupancy and latency samples no longer grow without bound;
 exact count/total fields keep the means and rates exact), the full slot
 lifecycle (submit → admit → chunk-step → harvest/evict) as JSON-lines
-events, chunk dispatches and slot residencies as Chrome-trace spans on
-per-device/per-bucket tracks, and — with ``cfg.metrics`` — the in-jit
+events, the host phases of a tick (``step``, ``admit``, ``prep``,
+``chunk_dispatch``, ``harvest``) and slot residencies as Chrome-trace
+spans on per-device/per-bucket tracks — the phases also land in any live
+``jax.profiler`` capture as ``aco.*`` events, beside the device's
+operations — and, with ``cfg.metrics``, the in-jit
 StepMetrics rows carried next to the resident ColonyState, surfaced per
 result and in periodic snapshots.  Pass a ``telemetry=`` instance to
 export; the default private bundle costs microseconds per event.
@@ -108,12 +111,16 @@ class StreamRequest:
                 else float("inf"),
                 self.request_id)
 
-    def prep(self, bucket: int, cfg: aco.ACOConfig, nn_k: int) -> None:
+    def prep(self, bucket: int, cfg: aco.ACOConfig, nn_k: int,
+             tracer: obs.Tracer) -> None:
+        """Build the padded problem, tau0 and initial state once, in a
+        ``prep`` span."""
         if self.prob is None:
-            self.prob = batch_mod.padded_problem(
-                self.instance, bucket, nn_k, self.hyper)
-            self.state = engine.init_state(
-                self.instance, cfg, self.seed, bucket, self.hyper)
+            with tracer.span("prep", n=self.instance.n, bucket=bucket):
+                self.prob = batch_mod.padded_problem(
+                    self.instance, bucket, nn_k, self.hyper)
+                self.state = engine.init_state(
+                    self.instance, cfg, self.seed, bucket, self.hyper)
 
 
 class StreamingPool:
@@ -204,7 +211,7 @@ class StreamingPool:
         probs, states, idx, buds = [], [], [], []
         for i, req in assignments:
             assert self.requests[i] is None, f"slot {i} occupied"
-            req.prep(self.bucket, self.cfg, self.nn_k)
+            req.prep(self.bucket, self.cfg, self.nn_k, self.tel.tracer)
             probs.append(req.prob)
             states.append(req.state)
             idx.append(i)
@@ -255,16 +262,19 @@ class StreamingPool:
         the only references — ``self.states``/``self.since``/``self.mets``
         — are immediately rebound to the outputs (DESIGN.md §10).
 
-        The dispatch is recorded as a span on this pool's device/bucket
-        track (async: the span covers enqueue, not device wall time) and,
-        when a jax.profiler capture is live, as a named profiler step."""
+        The dispatch is recorded as a ``chunk_dispatch`` span on this
+        pool's device/bucket track (async: the span covers the enqueue, not
+        device wall time), with the pool's occupancy (``occupied`` of
+        ``slots``) and padding (``cities``, the real cities of the occupied
+        slots, of ``occupied * bucket``)."""
+        live = [r for r in self.requests if r is not None]
         with self.tel.tracer.span("chunk_dispatch", process=self.dev_label,
                                   thread=f"b{self.bucket}",
-                                  occupied=self.occupied, chunk=chunk,
-                                  request_ids=[r.request_id
-                                               for r in self.requests
-                                               if r is not None]), \
-                self.tel.step_annotation("chunk_step", step_num=self.chunks):
+                                  occupied=len(live), slots=self.slots,
+                                  bucket=self.bucket,
+                                  cities=sum(r.instance.n for r in live),
+                                  chunk=chunk,
+                                  request_ids=[r.request_id for r in live]):
             out = engine.run_batch(
                 self.problem, self.states, self.budgets, self.cfg, chunk,
                 self.patience, self.since, donate=True, mets=self.mets,
@@ -277,14 +287,20 @@ class StreamingPool:
 
     def harvest(self) -> list[SolveResult]:
         """Collect every occupied slot whose done mask fired; free the slot
-        (budget 0 refreezes it) so the next admit round can refill it."""
-        it = np.asarray(self.states.iteration)
-        done = it >= np.asarray(self.budgets)
-        if self.patience > 0:
-            done = done | (np.asarray(self.since) >= self.patience)
-        return self._free_slots(
-            [i for i, r in enumerate(self.requests)
-             if r is not None and done[i]])
+        (budget 0 refreezes it) so the next admit round can refill it.
+        A ``harvest`` span covers the read-backs and the freeing."""
+        with self.tel.tracer.span("harvest", process=self.dev_label,
+                                  thread=f"b{self.bucket}",
+                                  bucket=self.bucket) as span:
+            it = np.asarray(self.states.iteration)
+            done = it >= np.asarray(self.budgets)
+            if self.patience > 0:
+                done = done | (np.asarray(self.since) >= self.patience)
+            out = self._free_slots(
+                [i for i, r in enumerate(self.requests)
+                 if r is not None and done[i]])
+            span["harvested"] = len(out)
+        return out
 
     def evict_expired(self, now: float) -> list[SolveResult]:
         """Evict occupied slots whose request deadline has passed: the
@@ -534,7 +550,7 @@ class StreamingSolverService:
         # refill surgery on the stepping critical path is only .at[ix].set)
         # — but only within the bounded look-ahead window.
         if len(self._waiting) < self.prep_ahead:
-            req.prep(req.bucket, self.cfg, self.cfg.nn_k)
+            req.prep(req.bucket, self.cfg, self.cfg.nn_k, self.tel.tracer)
         self._waiting.append(req)
         self._c_submitted.inc()
         self.slo.on_submit(tenant)
@@ -610,34 +626,40 @@ class StreamingSolverService:
         """Move waiting requests (priority desc, deadline asc, arrival)
         into free slots of their bucket's pools, each to the currently
         least-occupied pool (deterministic: ties break to the lowest
-        device index).  Returns #admitted."""
+        device index), in an ``admit`` span.  Returns #admitted."""
         if not self._waiting:
             return 0
-        self._waiting.sort(key=StreamRequest.order_key)
-        fills: dict[tuple[int, int], list[tuple[int, StreamRequest]]] = {}
-        free: dict[int, list[list[int]]] = {}   # bucket -> per-pool slots
-        leftover: list[StreamRequest] = []
-        for req in self._waiting:
-            b = req.bucket
-            if b not in free:
-                free[b] = [p.free_slots() for p in self._bucket_pools(b)]
-            # least-occupied == most free slots (all pools are same size);
-            # the running pop keeps in-flight assignments counted.
-            j = max(range(len(free[b])), key=lambda k: len(free[b][k]))
-            if free[b][j]:
-                fills.setdefault((b, j), []).append((free[b][j].pop(0), req))
-            else:
-                leftover.append(req)
-        self._waiting = leftover
-        n = 0
-        for (b, j), assignments in fills.items():
-            self._pools[b][j].fill_slots(assignments)
-            n += len(assignments)
-        # Prefetch prep for the queue head (next harvest's refills) —
-        # between chunks, not inside the surgery itself.
-        for req in leftover[:self.prep_ahead]:
-            if req.prob is None:
-                req.prep(req.bucket, self.cfg, self.cfg.nn_k)
+        with self.tel.tracer.span("admit") as span:
+            self._waiting.sort(key=StreamRequest.order_key)
+            fills: dict[tuple[int, int],
+                        list[tuple[int, StreamRequest]]] = {}
+            free: dict[int, list[list[int]]] = {}  # bucket -> per-pool slots
+            leftover: list[StreamRequest] = []
+            for req in self._waiting:
+                b = req.bucket
+                if b not in free:
+                    free[b] = [p.free_slots()
+                               for p in self._bucket_pools(b)]
+                # least-occupied == most free slots (all pools are same
+                # size); the running pop keeps in-flight assignments
+                # counted.
+                j = max(range(len(free[b])), key=lambda k: len(free[b][k]))
+                if free[b][j]:
+                    fills.setdefault((b, j), []).append(
+                        (free[b][j].pop(0), req))
+                else:
+                    leftover.append(req)
+            self._waiting = leftover
+            n = 0
+            for (b, j), assignments in fills.items():
+                self._pools[b][j].fill_slots(assignments)
+                n += len(assignments)
+            # Prefetch prep for the queue head (next harvest's refills) —
+            # between chunks, not inside the surgery itself.
+            for req in leftover[:self.prep_ahead]:
+                req.prep(req.bucket, self.cfg, self.cfg.nn_k,
+                         self.tel.tracer)
+            span["admitted"] = n
         return n
 
     # ----------------------------------------------------------- eviction
@@ -698,28 +720,31 @@ class StreamingSolverService:
         All pools' chunk steps are dispatched before any harvest reads a
         result back: jax dispatch is async, so with per-device pools the
         D chunk programs execute concurrently across the mesh while the
-        host is still enqueueing/harvesting."""
-        results: list[SolveResult] = list(self._evict_expired())
-        self._admit()
-        stepped: list[StreamingPool] = []
-        for pool in self._all_pools():
-            if pool.occupied == 0:
-                continue
-            self._h_occupancy.observe(pool.occupied / pool.slots)
-            pool.step_chunk(self.chunk)         # async dispatch
-            stepped.append(pool)
-        for pool in stepped:
-            results.extend(pool.harvest())      # first device read-back
-        if results:
-            done = [r for r in results if not r.expired]
-            if done:
-                self._t_last_harvest = time.perf_counter()
-                self._c_completed.inc(len(done))
-            for r in done:
-                self._h_latency.observe(r.latency_s)
-                self._per_bucket_done[r.bucket] = \
-                    self._per_bucket_done.get(r.bucket, 0) + 1
-        self._maybe_snapshot()
+        host is still enqueueing/harvesting.  The tick is a ``step`` span
+        holding the ``admit``, ``chunk_dispatch`` and ``harvest`` spans."""
+        with self.tel.tracer.span("step", resident=self.resident,
+                                  waiting=self.waiting):
+            results: list[SolveResult] = list(self._evict_expired())
+            self._admit()
+            stepped: list[StreamingPool] = []
+            for pool in self._all_pools():
+                if pool.occupied == 0:
+                    continue
+                self._h_occupancy.observe(pool.occupied / pool.slots)
+                pool.step_chunk(self.chunk)         # async dispatch
+                stepped.append(pool)
+            for pool in stepped:
+                results.extend(pool.harvest())      # first device read-back
+            if results:
+                done = [r for r in results if not r.expired]
+                if done:
+                    self._t_last_harvest = time.perf_counter()
+                    self._c_completed.inc(len(done))
+                for r in done:
+                    self._h_latency.observe(r.latency_s)
+                    self._per_bucket_done[r.bucket] = \
+                        self._per_bucket_done.get(r.bucket, 0) + 1
+            self._maybe_snapshot()
         return results
 
     def _maybe_snapshot(self) -> None:

@@ -125,17 +125,21 @@ def sparse_colony_step(problem: SparseProblem, state: SparseColonyState,
         key, k_tour = jax.random.split(state.key)
         k_q = None
 
-    if cfg.construction == "partial":
-        res = construct.partial_tours(
-            k_tour, problem, state.tau, state.ovf_city, state.ovf_tau,
-            state.best_tour, state.best_len, m, cfg.partial_window,
-            cfg.selection, cfg.alpha, cfg.beta, ewt,
-            use_pallas=cfg.use_pallas, draw_mode=cfg.draw_mode)
-    else:
-        res = construct.construct_sparse_tours(
-            k_tour, problem, state.tau, state.ovf_city, state.ovf_tau, m,
-            cfg.selection, cfg.alpha, cfg.beta, ewt,
-            use_pallas=cfg.use_pallas, draw_mode=cfg.draw_mode)
+    # Named scopes as in aco.colony_step (construct, deposit): the
+    # candidate-page weights are computed inside the construction loop, so
+    # this route has no separate choice phase, and it runs no local search.
+    with jax.named_scope("construct"):
+        if cfg.construction == "partial":
+            res = construct.partial_tours(
+                k_tour, problem, state.tau, state.ovf_city, state.ovf_tau,
+                state.best_tour, state.best_len, m, cfg.partial_window,
+                cfg.selection, cfg.alpha, cfg.beta, ewt,
+                use_pallas=cfg.use_pallas, draw_mode=cfg.draw_mode)
+        else:
+            res = construct.construct_sparse_tours(
+                k_tour, problem, state.tau, state.ovf_city, state.ovf_tau, m,
+                cfg.selection, cfg.alpha, cfg.beta, ewt,
+                use_pallas=cfg.use_pallas, draw_mode=cfg.draw_mode)
 
     it_best_idx = jnp.argmin(res.lengths)
     it_best_len = res.lengths[it_best_idx]
@@ -150,55 +154,58 @@ def sparse_colony_step(problem: SparseProblem, state: SparseColonyState,
     best_len = jnp.where(improved, it_best_len, state.best_len)
     best_tour = jnp.where(improved, it_best_tour, state.best_tour)
 
-    rho, q = cfg.rho, cfg.q
-    if cfg.variant == "as":
-        dep_tours, dep_w = res.tours, q / res.lengths
-    elif cfg.variant == "mmas":
-        if cfg.mmas_best == "global":
-            dep_tours, dep_w = best_tour[None, :], (q / best_len)[None]
+    with jax.named_scope("deposit"):
+        rho, q = cfg.rho, cfg.q
+        if cfg.variant == "as":
+            dep_tours, dep_w = res.tours, q / res.lengths
+        elif cfg.variant == "mmas":
+            if cfg.mmas_best == "global":
+                dep_tours, dep_w = best_tour[None, :], (q / best_len)[None]
+            else:
+                dep_tours = it_best_tour[None, :]
+                dep_w = (q / it_best_len)[None]
+        elif cfg.variant == "acs":
+            dep_tours = best_tour[None, :]
+            dep_w = (rho * q / best_len)[None]
         else:
-            dep_tours, dep_w = it_best_tour[None, :], (q / it_best_len)[None]
-    elif cfg.variant == "acs":
-        dep_tours = best_tour[None, :]
-        dep_w = (rho * q / best_len)[None]
-    else:
-        raise ValueError(f"unknown variant {cfg.variant}")
+            raise ValueError(f"unknown variant {cfg.variant}")
 
-    adopt = cfg.variant in ("mmas", "acs") and cfg.sparse_overflow > 0
-    # Transient fp32 views for the update/clamp path (identity for fp32);
-    # construction above consumed the resident payload directly.
-    tau_full = quant.dequantise(state.tau) if quantised else state.tau
-    ovf_full = quant.dequantise(state.ovf_tau) if quantised else state.ovf_tau
-    tau, tau_def, ovf_city, ovf_tau = pheromone.update_sparse(
-        tau_full, state.tau_def, state.ovf_city, ovf_full,
-        problem.cand, dep_tours, dep_w, rho, adopt, n_act)
+        adopt = cfg.variant in ("mmas", "acs") and cfg.sparse_overflow > 0
+        # Transient fp32 views for the update/clamp path (identity for fp32);
+        # construction above consumed the resident payload directly.
+        tau_full = quant.dequantise(state.tau) if quantised else state.tau
+        ovf_full = (quant.dequantise(state.ovf_tau) if quantised
+                    else state.ovf_tau)
+        tau, tau_def, ovf_city, ovf_tau = pheromone.update_sparse(
+            tau_full, state.tau_def, state.ovf_city, ovf_full,
+            problem.cand, dep_tours, dep_w, rho, adopt, n_act)
 
-    n_eff = n if n_act is None else n_act
-    clamp = None
-    if cfg.variant == "mmas":
-        tau_max = q / (rho * best_len)
-        tau_min = tau_max / (2.0 * n_eff)
-        tau = jnp.clip(tau, tau_min, tau_max)
-        tau_def = jnp.clip(tau_def, tau_min, tau_max)
-        ovf_tau = jnp.clip(ovf_tau, tau_min, tau_max)
-        clamp = (tau_min, tau_max)
-    elif cfg.variant == "acs":
-        tau0 = q / (n_eff * jnp.maximum(best_len, 1e-9))
-        tau, tau_def, ovf_tau = pheromone.local_update_acs_sparse(
-            tau, tau_def, ovf_tau, problem.cand, res.tours, cfg.xi, tau0,
-            n_act)
+        n_eff = n if n_act is None else n_act
+        clamp = None
+        if cfg.variant == "mmas":
+            tau_max = q / (rho * best_len)
+            tau_min = tau_max / (2.0 * n_eff)
+            tau = jnp.clip(tau, tau_min, tau_max)
+            tau_def = jnp.clip(tau_def, tau_min, tau_max)
+            ovf_tau = jnp.clip(ovf_tau, tau_min, tau_max)
+            clamp = (tau_min, tau_max)
+        elif cfg.variant == "acs":
+            tau0 = q / (n_eff * jnp.maximum(best_len, 1e-9))
+            tau, tau_def, ovf_tau = pheromone.local_update_acs_sparse(
+                tau, tau_def, ovf_tau, problem.cand, res.tours, cfg.xi, tau0,
+                n_act)
 
-    # Quantise-on-store: pages and overflow each requantise with their
-    # own key; metrics below read the exact fp32 tau of this step.
-    tau_store, ovf_store = tau, ovf_tau
-    if quantised:
-        k_q1, k_q2 = jax.random.split(k_q)
-        tau_store = quant.requantise(
-            tau, state.tau, cfg.tau_dtype,
-            quant.round_key(cfg.tau_round, k_q1))
-        ovf_store = quant.requantise(
-            ovf_tau, state.ovf_tau, cfg.tau_dtype,
-            quant.round_key(cfg.tau_round, k_q2))
+        # Quantise-on-store: pages and overflow each requantise with their
+        # own key; metrics below read the exact fp32 tau of this step.
+        tau_store, ovf_store = tau, ovf_tau
+        if quantised:
+            k_q1, k_q2 = jax.random.split(k_q)
+            tau_store = quant.requantise(
+                tau, state.tau, cfg.tau_dtype,
+                quant.round_key(cfg.tau_round, k_q1))
+            ovf_store = quant.requantise(
+                ovf_tau, state.ovf_tau, cfg.tau_dtype,
+                quant.round_key(cfg.tau_round, k_q2))
 
     new_state = SparseColonyState(tau_store, tau_def, ovf_city, ovf_store,
                                   best_tour, best_len,

@@ -77,33 +77,39 @@ def test_histogram_empty_and_bad_window():
 # ------------------------------------------------------------------ tracer
 def test_tracer_chrome_trace_format():
     t = obs.Tracer()
-    with t.span("phase", process="dev0", thread="b16", k=1):
-        pass
+    with t.span("phase", process="dev0", thread="b16", k=1) as args:
+        args["done"] = 2                         # an arg known at the end
     t.complete("req0", 10.0, 25.0, process="dev0", thread="b16/s0")
-    t.instant("admit", process="dev0")
-    t.counter("occ", process="dev0", occupied=3)
     ch = t.to_chrome()
     evs = ch["traceEvents"]
     assert json.loads(json.dumps(ch))            # serializable
     # metadata names every (process, thread) track exactly once
     meta = [e for e in evs if e["ph"] == "M"]
-    assert {(m["name"], m["args"]["name"]) for m in meta} >= {
-        ("process_name", "dev0"), ("thread_name", "b16")}
+    assert {(m["name"], m["args"]["name"]) for m in meta} == {
+        ("process_name", "dev0"), ("thread_name", "b16"),
+        ("thread_name", "b16/s0")}
     spans = [e for e in evs if e["ph"] == "X"]
     assert {s["name"] for s in spans} == {"phase", "req0"}
     for s in spans:
         assert s["dur"] >= 0 and "pid" in s and "tid" in s
+    assert [s["args"] for s in spans if s["name"] == "phase"] == [
+        {"k": 1, "done": 2}]
     # interning is stable: same (process, thread) -> same ids
     assert t.track("dev0", "b16") == t.track("dev0", "b16")
-    assert {e["ph"] for e in evs} == {"M", "X", "i", "C"}
+    assert {e["ph"] for e in evs} == {"M", "X"}
 
 
 def test_tracer_bounded():
     t = obs.Tracer(max_events=3)
-    for i in range(5):
-        t.instant(f"e{i}")
+    for i in range(3):
+        with t.span(f"s{i}"):
+            pass
+    for i in range(2):
+        t.complete(f"c{i}", float(i), 1.0)
     assert t.dropped == 2
-    assert len(t.to_chrome()["traceEvents"]) == 3 + 2   # 3 kept + 2 meta
+    evs = t.to_chrome()["traceEvents"]
+    assert len(evs) == 3 + 2                     # 3 kept + 2 meta
+    assert [e["name"] for e in evs if e["ph"] == "X"] == ["s2", "c0", "c1"]
 
 
 def test_eventlog_bounded_and_file_mirror(tmp_path):
