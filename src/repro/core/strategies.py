@@ -58,10 +58,25 @@ def place_ants(key: Array, m: int, n: int,
     return jax.random.randint(key, (m,), 0, hi, dtype=jnp.int32)
 
 
+def _mark_visited(visited: Array, cities: Array) -> Array:
+    """Set ``visited[i, cities[i]]`` for every ant i, by a one-hot OR.
+
+    Bitwise the scatter ``visited.at[ants, cities].set(True)``: each row
+    gains one True, and an index past the last column matches none, as the
+    scatter drops it (cities are never negative).  One elementwise (m, n)
+    pass, where the scatter's per-ant writes took over half of a
+    1002-city iteration's device time on a TPU v5e.  The sparse path
+    (sparse/construct.py) keeps its scatter: at its n an (m, n) pass per
+    step outweighs its O(m k).
+    """
+    n = visited.shape[-1]
+    return visited | (jnp.arange(n, dtype=jnp.int32)[None, :]
+                      == cities[:, None])
+
+
 def _init_state(start: Array, n: int) -> TourState:
     m = start.shape[0]
-    visited = jnp.zeros((m, n), jnp.bool_).at[jnp.arange(m), start].set(True)
-    return TourState(start, visited)
+    return TourState(start, _mark_visited(jnp.zeros((m, n), jnp.bool_), start))
 
 
 def _finish(start: Array, steps: Array, dist: Array,
@@ -241,8 +256,6 @@ def _construct(key: Array, choice_info: Array, dist: Array, start: Array,
     else:
         step_impl = _STEPS[(method, selection, draw_mode)]
     st0 = _init_state(start, n)
-    m = start.shape[0]
-    ants = jnp.arange(m)
 
     def body(st: TourState, t: Array):
         k = jax.random.fold_in(key, t)
@@ -255,8 +268,7 @@ def _construct(key: Array, choice_info: Array, dist: Array, start: Array,
             # n_actual..n-1.  This invariant is what makes masked
             # tour-length, deposit and local search exact (DESIGN.md §8).
             nxt = jnp.where(t < extras["n_actual"], nxt, t).astype(jnp.int32)
-        visited = st.visited.at[ants, nxt].set(True)
-        return TourState(nxt, visited), nxt
+        return TourState(nxt, _mark_visited(st.visited, nxt)), nxt
 
     _, steps = jax.lax.scan(body, st0, jnp.arange(1, n))
     return _finish(start, steps, dist, extras["n_actual"] if masked else None)
@@ -319,12 +331,11 @@ def construct_tours(
         # custom injection path (un-cached trace)
         def _custom(key_, ci_, dist_, start_, extras_):
             st0 = _init_state(start_, n)
-            ants = jnp.arange(start_.shape[0])
 
             def body(st, t):
                 k = jax.random.fold_in(key_, t)
                 nxt = step_impl(k, ci_, st, t)
-                return TourState(nxt, st.visited.at[ants, nxt].set(True)), nxt
+                return TourState(nxt, _mark_visited(st.visited, nxt)), nxt
 
             _, steps = jax.lax.scan(body, st0, jnp.arange(1, n))
             return _finish(start_, steps, dist_)

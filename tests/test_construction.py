@@ -323,3 +323,129 @@ def test_kernel_route_support_matrix():
     # ...but any concrete scalar (python, numpy, or jax) is static-able
     for a in (1.5, np.float32(1.5), jnp.float32(1.5)):
         assert build(a).shape == (4,)
+
+
+# ------------------------------------------ visited mask: one-hot OR update
+def _scatter_mark(visited, cities):
+    """The tabu-mask update as it was: one scattered write per ant."""
+    return visited.at[jnp.arange(cities.shape[0]), cities].set(True)
+
+
+@jax.jit
+def _lengths_of(dist, tours, n_actual):
+    return strategies._finish(tours[:, 0], tours[:, 1:].T, dist,
+                              n_actual).lengths
+
+
+def _scatter_construct(key, dist, ci, m, method, n_actual, extras):
+    """The construction loop as it was before the one-hot visited update:
+    the same step from ``_STEPS``, the same scan, the tabu mask marked by
+    ``.at[ants, nxt].set(True)``."""
+    n = dist.shape[0]
+    kp, kc = jax.random.split(key)
+    start = strategies.place_ants(kp, m, n, n_actual)
+    if method == "fused":
+        step = strategies._make_fused_step("iroulette", 1.0, 2.0)
+    else:
+        step = strategies._STEPS[(method, "iroulette", "packed")]
+
+    def body(st, t):
+        nxt = step(jax.random.fold_in(kc, t), ci, st, t, extras)
+        if n_actual is not None:
+            nxt = jnp.where(t < extras["n_actual"], nxt, t).astype(jnp.int32)
+        return strategies.TourState(nxt, _scatter_mark(st.visited, nxt)), nxt
+
+    st0 = strategies.TourState(
+        start, _scatter_mark(jnp.zeros((m, n), jnp.bool_), start))
+    _, steps = jax.lax.scan(body, st0, jnp.arange(1, n))
+    return jnp.concatenate([start[None, :], steps], axis=0).T
+
+
+def _run_batch_leaves(insts, cfg):
+    b = batch_mod.make_batch(insts, 32, 6)
+    seeds = list(range(len(insts)))
+    res, _ = engine.run_batch(b.problem,
+                              engine.init_states(insts, cfg, seeds, 32),
+                              jnp.full((len(insts),), 3, jnp.int32), cfg, 3)
+    return [np.asarray(x) for x in jax.tree.leaves(res)]
+
+
+@pytest.mark.parametrize("method,masked", [
+    (meth, masked)
+    for meth in ("data_parallel", "task_choice", "nn_list", "nn_list_eager",
+                 "pallas", "fused")
+    for masked in (False, True)] + [("run_batch", True)])
+def test_one_hot_visited_bitwise_equals_scatter(method, masked, monkeypatch):
+    """The one-hot OR visited update builds the tours the scatter built:
+    construct_tours against a copy of the scatter loop, unmasked and with a
+    phantom tail, and engine.run_batch over 3 slots of uneven n_actual with
+    the scatter patched back in."""
+    if method == "run_batch":
+        insts = [tsp.random_instance(n, seed=i)
+                 for i, n in enumerate((13, 29, 21))]
+        cfg = aco.ACOConfig(iterations=3, variant="as")
+        new = _run_batch_leaves(insts, cfg)
+        jax.clear_caches()
+        monkeypatch.setattr(strategies, "_mark_visited", _scatter_mark)
+        try:
+            old = _run_batch_leaves(insts, cfg)
+        finally:
+            jax.clear_caches()
+        for a, b in zip(new, old):
+            np.testing.assert_array_equal(a, b)
+        return
+    n, n_act = (40, 27) if masked else (37, None)
+    m = 12
+    inst = tsp.random_instance(n_act or n, seed=5)
+    if masked:
+        prob = batch_mod.padded_problem(inst, n, nn_k=6)
+    else:
+        prob = aco.make_problem(inst, nn_k=6)
+    tau = jax.random.uniform(jax.random.fold_in(KEY, 21), (n, n)) + 0.2
+    ci = strategies.choice_matrix(tau, prob.eta, 1.0, 2.0)
+    key = jax.random.fold_in(KEY, 22)
+    kw = dict(method=method, selection="iroulette", nn=prob.nn, tau=tau,
+              eta=prob.eta, n_actual=prob.n_actual)
+    got = strategies.construct_tours(key, prob.dist, ci, m, **kw)
+    na = (jnp.asarray(n, jnp.int32) if n_act is None else prob.n_actual)
+    extras = {"tau": tau, "tau_scale": jnp.zeros((1, 1), jnp.float32),
+              "eta": prob.eta, "alpha": jnp.float32(1.0),
+              "beta": jnp.float32(2.0), "nn": prob.nn, "n_actual": na}
+    tours = jax.jit(_scatter_construct, static_argnums=(3, 4))(
+        key, prob.dist, ci, m, method, prob.n_actual, extras)
+    np.testing.assert_array_equal(np.asarray(got.tours), np.asarray(tours))
+    np.testing.assert_array_equal(
+        np.asarray(got.lengths),
+        np.asarray(_lengths_of(prob.dist, tours, prob.n_actual)))
+    for t in np.asarray(got.tours):
+        assert tsp.is_valid_tour(t)
+
+
+@pytest.mark.parametrize("masked", [False, True])
+def test_dense_construct_lowers_without_scatter(masked):
+    """The dense loop marks visited with no scatter (the scatter sat in the
+    init and in the loop body); the sparse loop keeps its own, on purpose."""
+    from repro.sparse import construct as sp_construct
+    from repro.sparse import store
+    inst = tsp.random_instance(24, seed=3)
+    prob = (batch_mod.padded_problem(inst, 32, nn_k=6) if masked
+            else aco.make_problem(inst, nn_k=6))
+
+    def build(key, ci):
+        return strategies.construct_tours(key, prob.dist, ci, 8,
+                                          n_actual=prob.n_actual)
+
+    text = jax.jit(build).lower(KEY, prob.eta).as_text()
+    assert "stablehlo.scatter" not in text
+    sp = store.make_sparse_problem(inst, 6)
+    k = sp.cand.shape[1]
+
+    def build_sparse(key, tau):
+        return sp_construct.construct_sparse_tours(
+            key, sp, tau, jnp.full((24, 0), store.OVF_EMPTY, jnp.int32),
+            jnp.zeros((24, 0), jnp.float32), 8, "iroulette", 1.0, 2.0,
+            inst.edge_weight_type)
+
+    sparse_text = jax.jit(build_sparse).lower(
+        KEY, jnp.ones((24, k), jnp.float32)).as_text()
+    assert "stablehlo.scatter" in sparse_text
