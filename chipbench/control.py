@@ -33,6 +33,9 @@ def control_numbers(cell: cells.Cell, seed: int, dtype: str,
                     fault=None) -> dict:
     from chipbench.harness import control, single
     conf = cell.config
+    if conf["variant"] != "as":
+        raise ValueError("the reference colony is Ant System only; "
+                         f"no control for variant {conf['variant']!r}")
     if cell.traffic["kind"] == "closed_loop":
         req = generator.single_instance(conf, seed)
         answer, deposit = control.reference_colony(
@@ -72,13 +75,13 @@ def main(argv=None) -> int:
             from chipbench.harness import control
             seen = {}
 
-            def evaluate(answers):
+            def evaluate(answers, conf):
                 seen["control"] = check.served_numbers(
-                    answers, lengths=control.bf16_length)
+                    answers, lengths=control.bf16_length, conf=conf)
                 seen["random_tour"] = check.served_numbers(
                     [control.random_tour(a, seed + i)
-                     for i, a in enumerate(answers)])
-                return check.served_numbers(answers)
+                     for i, a in enumerate(answers)], conf=conf)
+                return check.served_numbers(answers, conf=conf)
             rec, ok, rows = run.run_cell(cell, seed, args.seconds, False,
                                          devs, session.now(), evaluate)
             print(json.dumps({"seed": seed, "who": "program", "correct": ok,

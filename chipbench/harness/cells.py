@@ -7,6 +7,25 @@ metric is a file of its own, found from ``BENCHMARK.json``:
 - traffic mix:   ``chipbench/traffic/<traffic>.json``;
 - metric reader: ``chipbench/metrics/<metric name>.py``, a ``read(ctx)``
   that returns a number, or None when it finds nothing to read.
+
+A configuration file is one JSON object.  Its top-level keys are:
+
+- any field of ``repro.core.aco.ACOConfig`` but ``iterations`` and
+  ``seed`` (the drivers set those): ``variant``, ``alpha``, ``beta``,
+  ``rho``, ``q``, ``m``, ``selection``, ``construction``, ``deposit``,
+  ``nn_k``, ``local_search``, ``ls_tours``, ``ls_rounds``, ``mmas_best``,
+  ``sparse``, ``sparse_k`` and the rest; each reaches the program as it
+  stands, and a field the file leaves out keeps the program's default;
+- the descriptive keys ``DESCRIPTIVE``: ``name``, ``source``,
+  ``deployment``, ``n``, ``service`` (the streaming service's
+  ``max_batch``, ``min_bucket``, ``chunk``), ``instance`` (the closed-loop
+  instance: ``kind``, ``box``, ``edge_weight_type``, ``min_distance``),
+  ``guarantees``, ``reduced``, ``assumed``, ``limits`` (the numbers
+  ``correct`` compares, each with its limit), ``limits_why``,
+  ``not_checked`` and ``rehearse`` (overrides for a CPU rehearsal).
+
+Any other key is refused with ``ValueError`` when the cell is resolved,
+so a misspelt setting never runs the program's default in silence.
 """
 from __future__ import annotations
 
@@ -19,11 +38,13 @@ from . import session
 
 BENCH_DIR = os.path.join(session.ROOT, "chipbench")
 
-# Keys of a configuration file that are settings of ``repro.core.aco.
-# ACOConfig``; the rest describe the instance, the service and the check.
-ACO_KEYS = ("variant", "alpha", "beta", "rho", "q", "m", "selection",
-            "construction", "deposit", "nn_k", "tau_dtype", "use_pallas",
-            "draw_mode", "local_search")
+# Keys of a configuration file that describe the deployment, the service
+# and the check; every other key has to name a setting of ``ACOConfig``.
+DESCRIPTIVE = ("name", "source", "deployment", "n", "service", "instance",
+               "guarantees", "reduced", "assumed", "limits", "limits_why",
+               "not_checked", "rehearse")
+# ``ACOConfig`` fields the drivers set themselves.
+DRIVER_SET = ("iterations", "seed")
 
 
 @dataclasses.dataclass
@@ -53,6 +74,8 @@ def resolve(bench: dict, name: str) -> Cell:
     conf = {c["name"]: c for c in bench["configs"]}[w["config"]]
     with open(os.path.join(session.ROOT, conf["file"])) as f:
         config = json.load(f)
+    session.add_program_to_path()
+    check_keys(config)
     with open(os.path.join(BENCH_DIR, "traffic", w["traffic"] + ".json")) as f:
         traffic = json.load(f)
     return Cell(
@@ -71,9 +94,33 @@ def reader(metric_name: str):
     return mod.read
 
 
-def aco_config(config: dict, **over):
+def aco_fields() -> tuple[str, ...]:
+    """The ``ACOConfig`` fields a configuration file may set."""
     from repro.core import aco
-    kw = {k: config[k] for k in ACO_KEYS if k in config}
+    return tuple(f.name for f in dataclasses.fields(aco.ACOConfig)
+                 if f.name not in DRIVER_SET)
+
+
+def check_keys(config: dict) -> None:
+    """Refuse a top-level key that is neither descriptive nor a setting
+    of ``ACOConfig`` (the drivers' own ``iterations`` and ``seed``
+    included)."""
+    known = set(DESCRIPTIVE) | set(aco_fields())
+    bad = sorted(set(config) - known)
+    if bad:
+        raise ValueError(
+            f"configuration {config.get('name')!r}: unknown key(s) {bad}; "
+            f"a key is one of {list(DESCRIPTIVE)} or an ACOConfig field "
+            f"other than {list(DRIVER_SET)}")
+
+
+def aco_config(config: dict, **over):
+    """The ``ACOConfig`` the configuration states: every key that names
+    one of its fields, with the drivers' ``over`` (``iterations``)."""
+    from repro.core import aco
+    check_keys(config)
+    fields = aco_fields()
+    kw = {k: config[k] for k in fields if k in config}
     kw.update(over)
     return aco.ACOConfig(**kw)
 

@@ -198,6 +198,24 @@ def extract(data, hlo_texts) -> Phases:
     return Phases(spans=spans, ops=ops)
 
 
+def program_texts(caches) -> list[str]:
+    """The text of every compiled program the ``ProgramCache``s hold (the
+    cache keeps them private)."""
+    return [c.as_text() for pc in caches for c in pc._programs.values()]
+
+
+def layer_ctx(data, caches) -> dict:
+    """What the per-layer readers take from a capture besides its
+    summary: ``phases``, its spans and operations with their colony
+    scopes read from the compiled programs of ``caches``, and ``scopes``,
+    each device's busy union per scope; None for both without a
+    capture."""
+    if data is None:
+        return {"phases": None, "scopes": None}
+    ph = extract(data, program_texts(caches))
+    return {"phases": ph, "scopes": ph.scopes()}
+
+
 def scopes_to_json(scopes: dict[str, dict[str, np.ndarray]]) -> dict:
     return {d: {sc: iv.tolist() for sc, iv in per.items()}
             for d, per in scopes.items()}

@@ -22,7 +22,7 @@ from typing import Optional
 
 import numpy as np
 
-from . import cells, check, generator, session
+from . import cells, check, generator, phases, session
 from .result import RunRecord
 
 
@@ -40,6 +40,7 @@ class Served:
     trace_span: tuple
     summary: object
     compiles: int
+    data: object = None         # the capture, read again after the drain
 
 
 def build(cell: cells.Cell, devs, seed: int):
@@ -102,7 +103,7 @@ def offer(svc, traffic: dict, schedule: list, seconds: float,
     capture = session.Capture() if trace else None
     traced: Optional[object] = None
     tr0 = tr1 = None
-    summary = None
+    summary = data = None
     nxt = 0
     while True:
         now = session.now()
@@ -114,7 +115,7 @@ def offer(svc, traffic: dict, schedule: list, seconds: float,
         elif traced is not None and now >= tr0 + trace_s:
             traced.__exit__(None, None, None)
             traced, tr1 = None, session.now()
-            summary = _reduce(capture)
+            summary, data = _reduce(capture)
         counter.on = t0 <= now < t1
         if nxt < len(schedule) and due[nxt] <= now:
             with session.span("bench.submit"):
@@ -146,21 +147,23 @@ def offer(svc, traffic: dict, schedule: list, seconds: float,
     if traced is not None:
         traced.__exit__(None, None, None)
         tr1 = session.now()
-        summary = _reduce(capture)
+        summary, data = _reduce(capture)
     counter.close()
     return Served(due=due, submit_t=submit_t, done_t=done_t,
                   results=results, rid_to_idx=rid_to_idx, t0=t0, t1=t1,
                   trace_span=(tr0, tr1), summary=summary,
-                  compiles=counter.count)
+                  compiles=counter.count, data=data)
 
 
 def _reduce(capture):
     """Stop the capture as soon as the traced part ends (a long capture
-    overflows the device's trace buffers) and reduce it."""
+    overflows the device's trace buffers) and reduce it; returns the
+    summary and the capture's data."""
     from . import xplane
-    summary = xplane.reduce(xplane.extract(capture.stop()))
+    data = capture.stop()
+    summary = xplane.reduce(xplane.extract(data))
     capture.cleanup()
-    return summary
+    return summary, data
 
 
 def window_metrics(s: Served, window_idx: np.ndarray, seconds: float,
@@ -212,6 +215,7 @@ def run(cell: cells.Cell, seed: int, seconds: float, trace: bool, devs,
         "queue_wait_s": waits,
         "completed": int(((s.done_t >= tr0) & (s.done_t < tr1)).sum()),
         "window_compiles": s.compiles,
+        **phases.layer_ctx(s.data, [svc.programs]),
     }
 
     # ---- answers of every request due in the window; then free the state
@@ -234,7 +238,7 @@ def run(cell: cells.Cell, seed: int, seconds: float, trace: bool, devs,
                 "iterations": s.results[i].iterations, "budget": budget}
                for i in win.tolist() if i in s.results]
     del svc, events
-    nums = (evaluate or check.served_numbers)(answers)
+    nums = (evaluate or check.served_numbers)(answers, conf=cell.config)
     # every request due before the window closed is answered: those of
     # the window by the end of the drain, and those of set-up too
     nums["missing"] = float(rec.failed + ladder_left

@@ -12,17 +12,23 @@ the harvest have run before the window.  The window calls ``step`` until
 completed in the window over the window's length.  A traced run measures
 the same window and captures its first ``trace_s`` (a long capture
 overflows the device's trace buffers), so it harvests and checks as many
-solves as an untraced one.
+solves as an untraced one; the colony scopes of the captured operations
+are read from it once the window has closed.
 
 Before every step the pheromone matrix is copied (one device copy), so the
 window's last chunk that was not a refill can be checked against the
-reference once the window has closed.
+reference once the window has closed.  The check follows the
+configuration's ``variant``: Ant System's deposit (every ant, q / L each)
+for ``as``, the MAX-MIN Ant System's one deposited tour and clamp for
+``mmas``; any other variant is refused, never passed in silence.  Where
+the configuration runs 2-opt, every answer's tour is also held to a 2-opt
+local optimum (``check.served_numbers``).
 """
 from __future__ import annotations
 
 import numpy as np
 
-from . import cells, check, generator, reference, session
+from . import cells, check, generator, phases, reference, session
 from .result import RunRecord
 
 
@@ -33,6 +39,7 @@ def run(cell: cells.Cell, seed: int, seconds: float, trace: bool, devs,
     from repro.solver import streaming
 
     conf, traffic = cell.config, cell.traffic
+    pheromone_check(conf)           # refuse an unchecked variant up front
     svc_conf = conf["service"]
     chunk = int(svc_conf["chunk"])
     budget = int(traffic["iterations"])
@@ -86,7 +93,7 @@ def run(cell: cells.Cell, seed: int, seconds: float, trace: bool, devs,
         capture.start()
     traced = session.span("bench.traced")
     traced_its = None   # iterations completed in the traced part
-    summary = None
+    summary = data = None
     steps = iterations = 0
     plain = None        # (tau before, iterations) of the last plain step
     pair = None         # (tau before, tau after, iterations, best after)
@@ -121,7 +128,8 @@ def run(cell: cells.Cell, seed: int, seconds: float, trace: bool, devs,
                 traced_its = iterations
                 if capture is not None:
                     from . import xplane
-                    summary = xplane.reduce(xplane.extract(capture.stop()))
+                    data = capture.stop()
+                    summary = xplane.reduce(xplane.extract(data))
                     capture.cleanup()
             if session.now() - t0 >= seconds:
                 break
@@ -133,7 +141,8 @@ def run(cell: cells.Cell, seed: int, seconds: float, trace: bool, devs,
     rec.e2e["iters_per_s"] = iterations / (t1 - t0)
     rec.memory_peak_bytes = session.memory_peak_bytes(devs)
     rec.layer_ctx = {"summary": summary, "iterations": traced_its,
-                     "window_compiles": counter.count, "chips": len(devs)}
+                     "window_compiles": counter.count, "chips": len(devs),
+                     **phases.layer_ctx(data, [progs])}
     counter.close()
 
     # ---- the timed path's answers; then the program's state is freed
@@ -165,16 +174,51 @@ def run(cell: cells.Cell, seed: int, seconds: float, trace: bool, devs,
 
 def numbers(answers: list[dict], deposit: dict, conf: dict,
             lengths=None) -> dict:
-    """The cell's numbers: every solve's best tour and reported length,
-    and the deposit of the window's last chunk that was not a refill."""
-    out = check.served_numbers(answers, lengths)
+    """The cell's numbers: every solve's best tour and reported length
+    (with the 2-opt gain left where the configuration runs 2-opt), and
+    the pheromone check that the configuration's ``variant`` calls for,
+    on the window's last chunk that was not a refill."""
+    names, pheromone_numbers = pheromone_check(conf)
+    out = check.served_numbers(answers, lengths, conf)
     if deposit["tau_before"] is None:
-        out["dep_asym"] = out["dep_rowsum_spread"] = float("inf")
-        out["dep_weight_over"] = out["dep_weight_under"] = float("inf")
-        return out
+        out.update(dict.fromkeys(names, float("inf")))
+    else:
+        out.update(pheromone_numbers(deposit, conf))
+    return out
+
+
+def _as_numbers(deposit: dict, conf: dict) -> dict:
+    """Ant System: every ant deposits (``reference.deposit_numbers``)."""
     m = int(conf["m"]) if conf.get("m") else int(conf["n"])
-    out.update(reference.deposit_numbers(
+    return reference.deposit_numbers(
         deposit["tau_before"], deposit["tau_after"], int(conf["n"]), m,
         int(deposit["chunk"]), float(conf["rho"]),
-        float(conf.get("q", 1.0)), deposit["best_len"]))
-    return out
+        float(conf.get("q", 1.0)), deposit["best_len"])
+
+
+def _mmas_numbers(deposit: dict, conf: dict) -> dict:
+    """MAX-MIN Ant System: one tour deposits, then the clamp
+    (``reference.mmas_numbers``)."""
+    return reference.mmas_numbers(
+        deposit["tau_before"], deposit["tau_after"], int(conf["n"]),
+        int(deposit["chunk"]), float(conf["rho"]),
+        float(conf.get("q", 1.0)), deposit["best_len"])
+
+
+# variant -> (the numbers its pheromone check reads, the check)
+PHEROMONE_CHECKS = {
+    "as": (("dep_asym", "dep_rowsum_spread", "dep_weight_over",
+            "dep_weight_under"), _as_numbers),
+    "mmas": (("dep_asym", "mmas_bound_err", "mmas_rows_over",
+              "mmas_rows_under"), _mmas_numbers),
+}
+
+
+def pheromone_check(conf: dict):
+    """The configuration's variant's pheromone check; ValueError for a
+    variant that has none."""
+    if conf["variant"] not in PHEROMONE_CHECKS:
+        raise ValueError(f"no pheromone check for variant "
+                         f"{conf['variant']!r}; known: "
+                         f"{sorted(PHEROMONE_CHECKS)}")
+    return PHEROMONE_CHECKS[conf["variant"]]

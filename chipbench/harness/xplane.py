@@ -4,16 +4,17 @@ Two steps, kept apart so the second can be tested on a small recorded trace:
 
 1. ``extract`` reads a ``jax.profiler.ProfileData`` into plain lists: the
    programs each device ran (start, end, name), each operation's device
-   time in the window, and the benchmark's own host spans (``bench.*``
-   ``TraceAnnotation``s, with their arguments).
+   time in the window, and the host spans (``TraceAnnotation``s, with
+   their arguments) of the benchmark (``bench.*``) and of the solver's
+   streaming service (``aco.*``, read by ``phases.py``).
 2. ``reduce`` turns those lists into the numbers the per-layer readers use.
 
 Busy time is the union of the intervals in which a program ran on a
 device (every operation runs inside one), clipped to the traced window
 (the ``bench.traced`` span).  Idle is
 the rest of the window.  A gap in a device's busy union is labelled by the
-innermost ``bench.*`` host span that covers its midpoint, so the breakdown
-says what the host was doing while the chip waited.
+innermost host span that covers its midpoint, so the breakdown says what
+the host was doing while the chip waited.
 """
 from __future__ import annotations
 
@@ -22,7 +23,8 @@ from typing import Optional
 
 import numpy as np
 
-SPAN_PREFIX = "bench."
+# host spans kept: the benchmark's own, and the solver's (phases.PREFIX)
+SPAN_PREFIXES = ("bench.", "aco.")
 WINDOW_SPAN = "bench.traced"
 WAIT_SPAN = "bench.wait"          # the host has no request to serve
 # A TPU plane has one event per program run ("XLA Modules") and one per
@@ -72,13 +74,13 @@ def _is_device_plane(name: str) -> bool:
 
 def extract(data) -> Trace:
     """Program runs and operation totals of every device plane, and the
-    ``bench.*`` host spans, of a ProfileData."""
+    ``bench.*`` and ``aco.*`` host spans, of a ProfileData."""
     spans: list[tuple[str, int, int, dict]] = []
     for plane in data.planes:
         if plane.name.startswith("/host:"):
             for line in plane.lines:
                 for ev in line.events:
-                    if ev.name.startswith(SPAN_PREFIX):
+                    if ev.name.startswith(SPAN_PREFIXES):
                         spans.append((ev.name, int(ev.start_ns),
                                       int(ev.start_ns + ev.duration_ns),
                                       {str(k): v for k, v in ev.stats}))
@@ -224,8 +226,9 @@ class Summary:
 
     def idle_gaps(self, k: int = 10) -> list[list]:
         """Idle time in the window by the host span that covers each gap's
-        midpoint (the innermost ``bench.*`` span; ``no span`` when none),
-        summed over gaps and averaged over devices, longest first."""
+        midpoint (the innermost ``bench.*`` or ``aco.*`` span; ``no span``
+        when none), summed over gaps and averaged over devices, longest
+        first."""
         lo, hi = self.window_ns
         spans = [s for s in self.spans
                  if s[0] != WINDOW_SPAN and s[2] > lo and s[1] < hi]

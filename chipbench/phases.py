@@ -5,16 +5,16 @@
 
 The run is the one ``run.py --trace 1`` makes (the same set-up, window,
 capture, reduction by ``harness/xplane.py`` and check), so its end-to-end
-numbers are those of a traced run.  Once the window is over the same
-capture is read again by ``harness/phases.py``, for the solver's ``aco.*``
-host spans and the colony step's named scopes (from the text of the
-cell's compiled programs).  The last line of standard output is
-one JSON object: the traced run's end-to-end numbers and per-layer
-metrics, the breakdown with the ``aco.*`` spans painted in, device time
-per colony scope (ms per iteration where the cell counts iterations) and
-what lies outside every scope, device idle within each host phase's self
-time as a share of the resident time, and slot occupancy and padding fill
-over the chunk dispatches.
+numbers are those of a traced run.  It reads the capture's phases that
+the per-layer readers read (``harness/phases.py``: the solver's ``aco.*``
+host spans and the colony step's named scopes, from the text of the
+cell's compiled programs), further than the readers do.  The last line
+of standard output is one JSON object: the traced run's end-to-end
+numbers and per-layer metrics, the breakdown with the ``aco.*`` spans
+painted in, device time per colony scope (ms per iteration where the
+cell counts iterations) and what lies outside every scope, device idle
+within each host phase's self time as a share of the resident time, and
+slot occupancy and padding fill over the chunk dispatches.
 
 ``--span-cost`` first times one ``Tracer.span`` with no capture running
 and inside a capture.  ``--fixture`` writes a 10 ms piece of the capture
@@ -232,31 +232,8 @@ def main(argv=None) -> int:
     if args.span_cost:
         out["span_cost"] = span_cost()
 
-    got: dict = {}
-    plain_extract = xplane.extract
-    # The cell's program caches: their compiled modules give each
-    # operation in the capture its op_name.
-    from repro.solver import programs as programs_mod
-    plain_cache = programs_mod.ProgramCache
-    caches = []
-
-    class KeptCache(plain_cache):
-        def __init__(self, *a, **kw):
-            super().__init__(*a, **kw)
-            caches.append(self)
-
-    def extract(data):
-        got["data"] = data          # read again once the window is over
-        return plain_extract(data)
-
-    xplane.extract = extract
-    programs_mod.ProgramCache = KeptCache
-    try:
-        rec, correct, _ = run.run_cell(cell, args.seed, args.seconds, True,
-                                       devs, T_START)
-    finally:
-        xplane.extract = plain_extract
-        programs_mod.ProgramCache = plain_cache
+    rec, correct, _ = run.run_cell(cell, args.seed, args.seconds, True,
+                                   devs, T_START)
     ctx = rec.layer_ctx
     summary = ctx.get("summary")
     out.update(correct=bool(correct), attempted=rec.attempted,
@@ -264,10 +241,8 @@ def main(argv=None) -> int:
                traced_e2e=rec.e2e,
                per_layer={m["name"]: cells.reader(m["name"])(ctx)
                           for m in cell.per_layer})
-    if summary is not None and "data" in got:
-        ph = phases.extract(got.pop("data"), [
-            c.as_text() for pc in caches for c in pc._programs.values()])
-        summary.spans = summary.spans + ph.spans
+    ph = ctx.get("phases")
+    if summary is not None and ph is not None:
         out["busy_s"], out["window_s"] = summary.busy_s, summary.window_s
         out["breakdown"] = {"device_ops": summary.top_ops(10),
                             "idle_gaps": summary.idle_gaps(12)}

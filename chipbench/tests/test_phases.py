@@ -276,3 +276,82 @@ def test_plain_recorded_traces_reduce_as_before(path):
     assert phases.phase_idle(s) == pytest.approx({
         phases.OUTSIDE: s.idle_share_within(s.resident_intervals())},
         rel=0, abs=1e-12)
+
+
+def test_benchmark_extract_keeps_program_spans():
+    """``xplane.extract`` keeps the program's ``aco.*`` host spans beside
+    the benchmark's ``bench.*`` ones, and no other host event."""
+    data = type("D", (), {"planes": [
+        _Plane("/host:CPU", [_Line("python", [
+            _Ev("aco.step", 0, 60, [("resident", 1)]),
+            _Ev("bench.step", 0, 61), _Ev("PjitFunction(f)", 1, 2)])]),
+        _Plane("/device:TPU:0", [
+            _Line(xplane.MODULE_LINE, [_Ev("jit_f(1)", 5, 55)])])]})()
+    spans = xplane.extract(data).spans
+    assert [(n, a) for n, _, _, a in spans] == [
+        ("aco.step", {"resident": 1}), ("bench.step", {})]
+
+
+IDLE_READERS = {"prep_idle.serve": "aco.prep",
+                "admit_idle.serve": "aco.admit",
+                "dispatch_idle.serve": "aco.chunk_dispatch",
+                "harvest_idle.serve": "aco.harvest"}
+NEW_READERS = ("construct_device_ms.single", "deposit_device_ms.single",
+               *IDLE_READERS, "slot_occupancy.serve", "pad_fill.serve")
+
+
+def test_new_readers_on_the_synthetic_trace():
+    trace, scopes = synthetic()
+    ctx = {"summary": xplane.reduce(trace), "scopes": scopes,
+           "iterations": 4}
+    read = cells.reader
+    assert read("construct_device_ms.single")(ctx) == pytest.approx(20 / 4)
+    assert read("deposit_device_ms.single")(ctx) == pytest.approx(10 / 4)
+    want = {"aco.admit": 3, "aco.prep": 5, "aco.chunk_dispatch": 3,
+            "aco.harvest": 15}
+    for name, span in IDLE_READERS.items():
+        assert read(name)(ctx) == pytest.approx(want[span] / 90), name
+    assert read("slot_occupancy.serve")(ctx) == pytest.approx(3 / 16)
+    assert read("pad_fill.serve")(ctx) == pytest.approx(350 / 512)
+
+
+@pytest.mark.parametrize("path", SCOPED,
+                         ids=[os.path.basename(p) for p in SCOPED])
+def test_new_readers_on_recorded_scopes(path):
+    """The eight readers give the numbers the piece of a chip capture was
+    recorded with; the four host-phase idle shares, ``aco.step``'s own
+    and the idle outside every span add up to ``resident_idle.serve``."""
+    rec, trace, scopes = _load(path)
+    want = rec["expect_phases"]
+    ctx = {"summary": xplane.reduce(trace), "scopes": scopes,
+           "iterations": 2, "completed": 2, "chips": 1}
+    got = {name: cells.reader(name)(ctx) for name in NEW_READERS}
+    for sc in ("construct", "deposit"):
+        v = want["scope_busy_s"].get(sc)
+        assert got[f"{sc}_device_ms.single"] == (
+            None if v is None else pytest.approx(v * 1e3 / 2, rel=1e-12))
+    for name, span in IDLE_READERS.items():
+        assert got[name] == pytest.approx(want["phase_idle"].get(span, 0.0),
+                                          rel=1e-9, abs=1e-12), name
+    rest = want["phase_idle"]["aco.step"] + want["phase_idle"][
+        phases.OUTSIDE]
+    assert abs(sum(got[n] for n in IDLE_READERS) + rest
+               - cells.reader("resident_idle.serve")(ctx)) < 1e-9
+    assert got["slot_occupancy.serve"] == pytest.approx(
+        want["slot_occupancy"], rel=1e-12)
+    assert got["pad_fill.serve"] == pytest.approx(want["pad_fill"],
+                                                  rel=1e-12)
+
+
+@pytest.mark.parametrize("name", NEW_READERS)
+def test_new_readers_find_nothing(name):
+    """No capture, or (for a colony scope) no scopes or no iterations:
+    nothing to read."""
+    assert cells.reader(name)({"summary": None}) is None
+    if name.endswith(".single"):
+        trace, scopes = synthetic()
+        s = xplane.reduce(trace)
+        assert cells.reader(name)({"summary": s, "scopes": None,
+                                   "iterations": 4}) is None
+        assert cells.reader(name)({"summary": s, "scopes": scopes,
+                                   "iterations": 0}) is None
